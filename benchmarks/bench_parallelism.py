@@ -22,6 +22,7 @@ CACHE = "artifacts/bench/parallelism_cells.json"
 def _measure():
     # run in a subprocess-like late import so the 512-device XLA flag is
     # only forced when this benchmark actually executes
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
     from repro.launch.dryrun import default_parallel, lower_cell
     from repro.launch.hlo_analysis import analyze, classify_collectives

@@ -7,6 +7,7 @@ import dataclasses
 import gc
 import heapq
 import json
+import multiprocessing
 import os
 import random
 import time
@@ -45,6 +46,11 @@ def run_worlds(worlds: "dict[str, tuple]",
     contrast, should be measured *outside* any parallel phase (see
     ``bench_replay``'s headline run).
 
+    Workers are spawned, never forked: a parent that has already touched
+    JAX (the checkpoint bench does) may hold the accelerator, and only a
+    fresh interpreter is sure not to inherit that. The worlds themselves
+    are pure-Python replays and never touch JAX.
+
     Falls back to in-process sequential execution when
     ``REPRO_BENCH_SEQUENTIAL=1`` or the pool cannot be spawned; if the
     pool breaks mid-run (a worker crashed or was OOM-killed), only the
@@ -58,7 +64,9 @@ def run_worlds(worlds: "dict[str, tuple]",
         return {name: _run_world(w) for name, w in norm.items()}
     workers = max_workers or min(len(norm), os.cpu_count() or 2)
     try:
-        with concurrent.futures.ProcessPoolExecutor(workers) as pool:
+        with concurrent.futures.ProcessPoolExecutor(
+                workers, mp_context=multiprocessing.get_context("spawn")
+        ) as pool:
             futs = {name: pool.submit(_run_world, w)
                     for name, w in norm.items()}
             out: dict[str, Any] = {}

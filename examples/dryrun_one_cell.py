@@ -5,6 +5,7 @@ multi-pod dry-run, runnable standalone.
   PYTHONPATH=src python examples/dryrun_one_cell.py [arch] [shape]
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"     # CPU dry-run tool, never the chip
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 

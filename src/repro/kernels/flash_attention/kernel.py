@@ -13,16 +13,21 @@ tanh soft-capping. Grid: (batch, q_heads, q_blocks, kv_blocks).
 
 Position-based masking: both q and kv carry absolute positions; slots with
 position < 0 are padding. This makes full/SWA/ring-buffer caches uniform.
+Query positions enter as a (B, Sq, 1) column and KV positions as a
+(B, 1, Skv) row, so each position block meets the TPU's (8, 128) tiling at
+any batch size and the mask is a plain broadcast of the two.
 """
 from __future__ import annotations
 
 import functools
-import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.runtime import resolve_interpret
 
 NEG_INF = -2.0 ** 30
 
@@ -33,7 +38,7 @@ NEG_INF = -2.0 ** 30
 #: must have revisit factor 1 — a new revisit pattern is a perf bug until
 #: declared.
 STREAMING_OPERANDS = {
-    0: "q_positions re-read per q-head (tiny (1, block_q) i32 block)",
+    0: "q_positions re-read per q-head (tiny (block_q, 1) i32 block)",
     1: "kv_positions re-streamed per (head, q-block) with the KV walk",
     3: "K streamed over every (q-head, q-block): the FlashAttention "
        "trade — O(S^2) HBM reads bought back by never materializing S^2 "
@@ -56,19 +61,19 @@ def _kernel(q_pos_ref, kv_pos_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)            # (bq, D)
     k = k_ref[0, 0].astype(jnp.float32)            # (bk, D)
     v = v_ref[0, 0].astype(jnp.float32)            # (bk, D)
-    q_pos = q_pos_ref[0]                           # (bq,)
-    kv_pos = kv_pos_ref[0]                         # (bk,)
+    q_pos = q_pos_ref[0]                           # (bq, 1)
+    kv_pos = kv_pos_ref[0]                         # (1, bk)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
 
-    ok = kv_pos[None, :] >= 0
+    ok = kv_pos >= 0
     if causal:
-        ok &= kv_pos[None, :] <= q_pos[:, None]
+        ok = ok & (kv_pos <= q_pos)
     if window > 0:
-        ok &= (q_pos[:, None] - kv_pos[None, :]) < window
+        ok = ok & ((q_pos - kv_pos) < window)
     s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[:, :1]                          # (bq, 1)
@@ -94,11 +99,13 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            *, causal: bool = True, window: int = 0,
                            softcap: float = 0.0, block_q: int = 128,
                            block_kv: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, Sq, D); k, v: (B, KV, Skv, D); positions: (B, S*).
 
     Sq/Skv must be multiples of block_q/block_kv (ops.py pads). H % KV == 0.
+    ``interpret=None`` follows the platform (``runtime.resolve_interpret``).
     """
+    interpret = resolve_interpret(interpret)
     B, H, Sq, D = q.shape
     KV, Skv = k.shape[1], k.shape[2]
     assert H % KV == 0 and Sq % block_q == 0 and Skv % block_kv == 0
@@ -110,8 +117,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                           lambda b, h, iq, ik: (b, h, iq, 0))
     k_spec = pl.BlockSpec((1, 1, block_kv, D),
                           lambda b, h, iq, ik: (b, h // G, ik, 0))
-    qp_spec = pl.BlockSpec((1, block_q), lambda b, h, iq, ik: (b, iq))
-    kp_spec = pl.BlockSpec((1, block_kv), lambda b, h, iq, ik: (b, ik))
+    qp_spec = pl.BlockSpec((1, block_q, 1), lambda b, h, iq, ik: (b, iq, 0))
+    kp_spec = pl.BlockSpec((1, 1, block_kv), lambda b, h, iq, ik: (b, 0, ik))
     o_spec = pl.BlockSpec((1, 1, block_q, D),
                           lambda b, h, iq, ik: (b, h, iq, 0))
 
@@ -137,4 +144,4 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         interpret=interpret,
         **kwargs,
-    )(q_positions, kv_positions, q, k, v)
+    )(q_positions.reshape(B, Sq, 1), kv_positions.reshape(B, 1, Skv), q, k, v)

@@ -7,6 +7,7 @@ by construction); padded query rows are sliced off on return.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: int = 0,
                     softcap: float = 0.0, block_q: int = 128,
                     block_kv: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, D); k, v: (B, Skv, KV, D); positions (B, S*) or (S*,)."""
     B, Sq, H, D = q.shape
     Skv = k.shape[1]

@@ -8,10 +8,13 @@ axis must be resident), rows are the grid. For d_model up to 8192 a
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.runtime import resolve_interpret
 
 #: RPL202 streaming allowance (see flash_attention.kernel): empty — every
 #: operand here is fetched exactly once (scale's index_map is constant, so
@@ -28,8 +31,10 @@ def _kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 def rmsnorm_pallas(x: jax.Array, scale: jax.Array, *, eps: float = 1e-5,
                    block_rows: int = 128,
-                   interpret: bool = True) -> jax.Array:
-    """x: (rows, d) with rows % block_rows == 0; scale: (d,)."""
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """x: (rows, d) with rows % block_rows == 0; scale: (d,).
+    ``interpret=None`` follows the platform."""
+    interpret = resolve_interpret(interpret)
     rows, d = x.shape
     assert rows % block_rows == 0
     grid = (rows // block_rows,)

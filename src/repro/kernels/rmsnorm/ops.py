@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,8 @@ from repro.utils import round_up
 
 @partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-5,
-            block_rows: int = 128, interpret: bool = True) -> jax.Array:
+            block_rows: int = 128,
+            interpret: Optional[bool] = None) -> jax.Array:
     shape = x.shape
     d = shape[-1]
     rows = 1

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ from repro.utils import round_up
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         Cm: jax.Array, *, chunk: int = 128,
-        interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+        interpret: Optional[bool] = None) -> tuple[jax.Array, jax.Array]:
     B, L, H, P = x.shape
     cl = min(chunk, round_up(L, 8))
     L_p = round_up(L, cl)

@@ -1,12 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"     # CPU dry-run tool, never the chip
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init). 512 placeholder host devices let ``jax.make_mesh``
-build the production meshes: 16x16 (one v5e pod) and 2x16x16 (two pods).
+The lines above MUST run before any other import (jax locks its platform
+and device count at first init). 512 placeholder host devices let
+``make_production_mesh`` build the production meshes: 16x16 (one v5e
+pod) and 2x16x16 (two pods).
 
 For every runnable cell this driver:
   1. builds the model + sharding rules,

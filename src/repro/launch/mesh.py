@@ -10,18 +10,32 @@ mesh prepends a DCN "pod" axis (2 pods = 512 chips).
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """A mesh whose axes are all Auto: GSPMD propagates shardings and the
+    rules' ``with_sharding_constraint`` hints steer it. (``jax.make_mesh``
+    defaults to Explicit axes, which refuse those hints.)"""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_host_mesh(model_axis: int = 1) -> Mesh:
-    """Tiny mesh over whatever devices exist (CPU tests / examples)."""
-    n = len(jax.devices())
-    data = max(n // model_axis, 1)
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+def make_host_mesh(model_axis: int = 1,
+                   devices: Optional[Sequence] = None) -> Mesh:
+    """(data, model) mesh over ``devices`` (default: every device)."""
+    devices = list(devices) if devices is not None else jax.devices()
+    data = max(len(devices) // model_axis, 1)
+    return make_mesh((data, model_axis), ("data", "model"),
+                     devices=devices[:data * model_axis])

@@ -5,6 +5,8 @@ variant and print its calibrated roofline terms.
       --shape train_4k --variant fsdp2d
 """
 import os
+# a CPU dry-run tool: 512 placeholder host devices, never the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
@@ -16,7 +18,7 @@ from repro.config import ParallelConfig, get_arch
 from repro.launch.calibrate import depth_variants, extrapolate
 from repro.launch.dryrun import default_parallel, lower_cell
 from repro.launch.hlo_analysis import analyze
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, \
     model_flops_per_device
 from repro.launch.shapes import SHAPES
@@ -70,13 +72,12 @@ def variant_parallel(name: str, base: ParallelConfig, cfg, mesh
 def measure(arch: str, shape_name: str, variant: str,
             ssm_overrides: dict | None = None,
             microbatches: int = 1) -> dict:
-    import jax
     from repro.config import TrainConfig
     shape = SHAPES[shape_name]
     if variant.endswith("_tp8"):
         # same 256 chips, deeper data parallelism: TP activation collectives
         # scale with tokens-in-flight per device, param gathers barely move
-        mesh = jax.make_mesh((32, 8), ("data", "model"))
+        mesh = make_mesh((32, 8), ("data", "model"))
         variant_base = variant[:-4]
     else:
         mesh = make_production_mesh()

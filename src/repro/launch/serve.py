@@ -19,15 +19,17 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import ParallelConfig, get_arch, get_smoke
+from repro.config import ModelConfig, ParallelConfig, get_arch, get_smoke
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import Model
-from repro.serve import make_serve_step
+from repro.serve import make_prefill, make_serve_step
 from repro.sharding import make_rules
 from repro.utils import logger
 
@@ -53,25 +55,39 @@ class ServeSession:
     """One resident serving instance: model + mesh + params built once.
 
     ``prefill(batch)`` runs the prompt pass and retains the KV caches and
-    last-step logits as session state; ``decode_step()`` appends one
-    greedy token per sequence. ``generate(prompt, n)`` chains the two.
+    last-step logits (``logits``) as session state; ``decode_step()``
+    appends one greedy token per sequence. ``generate(prompt, n)`` chains
+    the two.
+
+    ``arch`` names a registered config or is a ``ModelConfig`` itself;
+    ``max_len`` is the decode horizon the KV ring buffers hold (0: the
+    model's whole context); ``devices`` limits the mesh to those devices
+    (default: all of them).
     """
 
-    def __init__(self, arch: str = "smollm-360m", *, smoke: bool = False,
-                 model_axis: int = 1, seed: int = 0) -> None:
-        self.cfg = get_smoke(arch) if smoke else get_arch(arch)
-        self.mesh = make_host_mesh(model_axis)
-        self.parallel = ParallelConfig(remat="none", moe_impl="dense",
+    def __init__(self, arch: Union[str, ModelConfig] = "smollm-360m", *,
+                 smoke: bool = False, model_axis: int = 1, seed: int = 0,
+                 max_len: int = 0,
+                 devices: Optional[Sequence] = None) -> None:
+        if isinstance(arch, ModelConfig):
+            self.cfg = arch
+        else:
+            self.cfg = get_smoke(arch) if smoke else get_arch(arch)
+        self.mesh = make_host_mesh(model_axis, devices)
+        self.parallel = ParallelConfig(moe_impl="dense",
                                        shard_model_axes=model_axis > 1)
         self.model = Model(self.cfg, self.parallel,
                            make_rules(self.mesh, self.parallel))
         self._seed = seed
         self.params = self.model.init(jax.random.PRNGKey(seed))
-        self._prefill_fn = jax.jit(self.model.prefill)
-        self._step_fn = jax.jit(make_serve_step(self.model))
+        self._prefill_fn = jax.jit(make_prefill(self.model, max_len))
+        # the step consumes its caches: one KV copy in HBM, not two
+        self._step_fn = jax.jit(make_serve_step(self.model),
+                                donate_argnums=(1,))
         self._caches = None
         self._tok = None
         self._pos = 0
+        self.logits = None
 
     def make_batch(self, batch: int, prompt_len: int,
                    seed: int = 0) -> dict:
@@ -98,6 +114,7 @@ class ServeSession:
         logits.block_until_ready()
         dt = time.time() - t0
         self._caches = caches
+        self.logits = logits
         self._tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         self._pos = int(tokens.shape[1])
         return ServeTimings("prefill", dt, int(tokens.shape[0]),
@@ -113,7 +130,7 @@ class ServeSession:
         contiguous, non-overlapping token stream."""
         if self._caches is None:
             raise RuntimeError("decode_step before prefill")
-        tok = self._tok
+        tok, logits = self._tok, self.logits
         out = []
         t0 = time.time()
         for t in range(self._pos, self._pos + n_steps):
@@ -125,6 +142,7 @@ class ServeSession:
         dt = time.time() - t0
         self._pos += n_steps
         self._tok = tok
+        self.logits = logits
         gen = jnp.stack(out, axis=1)
         return gen, ServeTimings("decode", dt, int(tok.shape[0]),
                                  int(tok.shape[0] * n_steps))
@@ -149,6 +167,7 @@ class ServeSession:
         self._caches = None
         self._tok = None
         self._pos = 0
+        self.logits = None
         t0 = time.time()
         self.params = self.model.init(jax.random.PRNGKey(self._seed))
         jax.block_until_ready(self.params)
@@ -170,8 +189,10 @@ def main() -> None:
                          "models for transient-infra verdicts)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     sess = ServeSession(args.arch, smoke=args.smoke,
-                        model_axis=args.model_axis)
+                        model_axis=args.model_axis,
+                        max_len=args.prompt_len + args.gen)
     for i in range(args.restarts + 1):
         gen, tp, td = sess.generate(
             sess.make_batch(args.batch, args.prompt_len), args.gen)

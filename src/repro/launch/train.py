@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import tempfile
 import time
 from typing import Optional
 
@@ -27,10 +29,11 @@ from repro.core.ft.spike import SpikeDetector
 from repro.core.ft.supervisor import (JobContext, JobFailure, SpikeInterrupt,
                                       Supervisor)
 from repro.data import DataConfig, DataLoader, SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import Model
 from repro.sharding import make_rules
-from repro.train.optimizer import adamw_init
+from repro.train.optimizer import adamw_abstract, adamw_init
 from repro.train.train_step import compile_train_step
 from repro.utils import logger
 
@@ -84,12 +87,14 @@ class Trainer:
 
     def init_state(self) -> TrainerState:
         params = self.model.init(jax.random.PRNGKey(self.tcfg.seed))
-        return TrainerState(params, adamw_init(params), DataLoader(self.dataset))
+        opt = jax.jit(adamw_init, out_shardings=self.o_sh)(params)
+        return TrainerState(params, opt, DataLoader(self.dataset))
 
     def _restore(self, step: int, skip_ranges) -> TrainerState:
-        template = self.init_state()
+        abstract = self.model.abstract()
         (params, opt), extra = self.ckpt.restore(
-            step, (template.params, template.opt))
+            step, (abstract, adamw_abstract(abstract)),
+            shardings=(self.p_sh, self.o_sh))
         loader = DataLoader(self.dataset,
                             start_step=int(extra.get("data_step", step)),
                             skip_ranges=[tuple(r) for r in
@@ -150,30 +155,45 @@ class Trainer:
         return any(lo <= data_step < hi for lo, hi in state.loader.skip_ranges)
 
 
+def build_job(cfg, *, global_batch: int, seq_len: int, steps: int,
+              ckpt_every: int, ckpt_dir: str, model_axis: int = 1,
+              devices=None, **trainer_kw
+              ) -> tuple[Trainer, Supervisor, CheckpointManager]:
+    """The launcher's job: a Trainer on a host mesh, under a Supervisor."""
+    mesh = make_host_mesh(model_axis, devices)
+    parallel = ParallelConfig(moe_impl="dense",
+                              shard_model_axes=model_axis > 1)
+    tcfg = TrainConfig(global_batch=global_batch, seq_len=seq_len,
+                       total_steps=steps, warmup_steps=steps // 10)
+    model = Model(cfg, parallel, make_rules(mesh, parallel))
+    ckpt = CheckpointManager(ckpt_dir, keep=4)
+    trainer = Trainer(model, tcfg, mesh, parallel, ckpt, total_steps=steps,
+                      ckpt_every=ckpt_every, **trainer_kw)
+    sup = Supervisor(ckpt, FailureDiagnosisSystem(), SimulatedFleet(8))
+    return trainer, sup, ckpt
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config (CPU scale)")
     ap.add_argument("--steps", type=int, default=100)
-    ap.add_argument("--global-batch", type=int, default=8)
-    ap.add_argument("--seq-len", type=int, default=128)
+    # 4 x 1024 with "dots" remat fits one 16 GB v5e chip at smollm-360m
+    ap.add_argument("--global-batch", type=int, default=4)
+    ap.add_argument("--seq-len", type=int, default=1024)
     ap.add_argument("--ckpt-every", type=int, default=20)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_ckpt"))
     ap.add_argument("--model-axis", type=int, default=1)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
-    mesh = make_host_mesh(args.model_axis)
-    parallel = ParallelConfig(remat="none", moe_impl="dense",
-                              shard_model_axes=args.model_axis > 1)
-    tcfg = TrainConfig(global_batch=args.global_batch, seq_len=args.seq_len,
-                       total_steps=args.steps, warmup_steps=args.steps // 10)
-    model = Model(cfg, parallel, make_rules(mesh, parallel))
-    ckpt = CheckpointManager(args.ckpt_dir, keep=4)
-    trainer = Trainer(model, tcfg, mesh, parallel, ckpt,
-                      total_steps=args.steps, ckpt_every=args.ckpt_every)
-    sup = Supervisor(ckpt, FailureDiagnosisSystem(), SimulatedFleet(8))
+    trainer, sup, ckpt = build_job(
+        cfg, global_batch=args.global_batch, seq_len=args.seq_len,
+        steps=args.steps, ckpt_every=args.ckpt_every,
+        ckpt_dir=args.ckpt_dir, model_axis=args.model_axis)
     t0 = time.time()
     report = sup.run(trainer.job)
     ckpt.wait()

@@ -87,11 +87,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_kv: int = 512, softcap: float = 0.0) -> jax.Array:
     """Dispatch: Pallas TPU kernel when enabled, else the jnp oracle path."""
     from repro.kernels import runtime
-    if runtime.STATE.use_pallas and isinstance(window, int):
+    if runtime.STATE.use_pallas:
+        if not isinstance(window, int):
+            raise TypeError("the Pallas attention kernel takes a static int "
+                            f"window, got {type(window).__name__}")
         from repro.kernels.flash_attention import flash_attention as fa
         return fa(q, k, v, q_positions, kv_positions, causal=causal,
-                  window=window, softcap=softcap,
-                  interpret=runtime.STATE.interpret)
+                  window=window, softcap=softcap)
     return flash_attention_jnp(q, k, v, q_positions=q_positions,
                                kv_positions=kv_positions, window=window,
                                causal=causal, block_kv=block_kv,

@@ -246,8 +246,7 @@ def mamba_forward(params: Params, cfg: SSMConfig, x: jax.Array, *,
         from repro.kernels.ssd import ssd as ssd_kernel
         y, final_state = ssd_kernel(xh, dt_sp, A, Bi.reshape(b, L, G, N),
                                     Ci.reshape(b, L, G, N),
-                                    chunk=cfg.chunk_size,
-                                    interpret=runtime.STATE.interpret)
+                                    chunk=cfg.chunk_size)
         if not return_state:
             final_state = None
     else:

@@ -23,7 +23,7 @@ from repro.models import layers as L
 from repro.models import mamba as mamba_lib
 from repro.models import moe as moe_lib
 from repro.models.spec import ParamSpec, abstract_params, init_params, stack_specs
-from repro.sharding import Rules, constrain
+from repro.sharding import Rules, constrain, tree_shardings
 
 Params = Any
 
@@ -275,7 +275,14 @@ class Model:
         return out
 
     def init(self, key: jax.Array) -> Params:
-        return init_params(self.specs(), key)
+        """Materialize parameters under ``jit``: with rules, every leaf is
+        created directly on its shard (a model too big for one device
+        never passes through one). Same values as ``init_params``."""
+        specs = self.specs()
+        shardings = (tree_shardings(self.rules, specs)
+                     if self.rules is not None else None)
+        return jax.jit(partial(init_params, specs),
+                       out_shardings=shardings)(key)
 
     def abstract(self, shardings=None) -> Params:
         return abstract_params(self.specs(), shardings)

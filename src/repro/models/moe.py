@@ -31,7 +31,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.config import ModelConfig, MoEConfig, ParallelConfig
 from repro.models.layers import _act, mlp, mlp_specs
@@ -167,13 +166,13 @@ def moe_gshard(params: Params, cfg: MoEConfig, x: jax.Array, *,
     glu = "w3" in params
     w3 = params["w3"] if glu else jnp.zeros((E, 1, 1), params["w1"].dtype)
     espec = P(MODEL, None, None)                             # (E, d, eff) EP
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_gshard_local, cfg, act, dtype, C, glu, mesh.axis_names),
         mesh=mesh,
         in_specs=(P(None, None), espec, espec, espec,
                   P(dax if dax else None, MODEL, None)),
         out_specs=(P(dax if dax else None, MODEL, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params["router"], params["w1"], params["w2"], w3, x)
 
@@ -217,13 +216,13 @@ def moe_tp(params: Params, cfg: MoEConfig, x: jax.Array, *,
     M = mesh.shape[MODEL] if MODEL in mesh.axis_names else 1
     w3 = params["w3"] if glu else jnp.zeros((E, 1, M), params["w1"].dtype)
     espec = P(None, None, MODEL)                 # (E, d, eff): eff TP-sliced
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_tp_local, cfg, act, dtype, C, glu, mesh.axis_names),
         mesh=mesh,
         in_specs=(P(None, None), espec, P(None, MODEL, None), espec,
                   P(dax if dax else None, None, None)),
         out_specs=(P(dax if dax else None, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params["router"], params["w1"], params["w2"], w3, x)
 

@@ -56,12 +56,14 @@ def _init_one(spec: ParamSpec, key: jax.Array) -> jax.Array:
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
+    # the barrier keeps XLA from folding the scale into the sampler's own
+    # constants under jit, so a jitted init rounds exactly like an eager one
     if spec.init == "normal":
-        x = jax.random.normal(key, spec.shape, jnp.float32) * spec.stddev
-        return x.astype(spec.dtype)
+        x = jax.random.normal(key, spec.shape, jnp.float32)
+        return (jax.lax.optimization_barrier(x) * spec.stddev).astype(spec.dtype)
     if spec.init == "a_log":  # mamba: A in [1, 16), stored as log
         a = jax.random.uniform(key, spec.shape, jnp.float32, 1.0, 16.0)
-        return jnp.log(a).astype(spec.dtype)
+        return jnp.log(jax.lax.optimization_barrier(a)).astype(spec.dtype)
     raise ValueError(f"unknown init {spec.init!r}")
 
 

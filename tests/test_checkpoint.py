@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core.ft.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 from repro.utils import tree_allclose
 
 
@@ -88,7 +89,7 @@ def test_elastic_restore_resharded(tmp_path):
     mgr = CheckpointManager(str(tmp_path), keep=2)
     state = _state()
     mgr.save_sync(1, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = jax.tree_util.tree_map(
         lambda x: NamedSharding(mesh, P("data") if getattr(x, "ndim", 0) > 0
                                 else P()), state)

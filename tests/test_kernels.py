@@ -106,3 +106,43 @@ def test_ssd_head_blocked_equals_unblocked():
         y1 = ssd_chunked(x, dt, A, Bm, Cm, chunk=16, head_block=hb)
         np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    """Interpret off the TPU, compiled on it; interpret on a TPU is refused."""
+    from repro.kernels import runtime
+    assert runtime.resolve_interpret(None) is True
+    assert runtime.resolve_interpret(False) is False
+    monkeypatch.setattr(runtime.jax, "default_backend", lambda: "tpu")
+    assert runtime.resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="interpret"):
+        runtime.resolve_interpret(True)
+
+
+def test_pallas_attention_refuses_a_traced_window():
+    from repro.kernels import runtime
+    from repro.models import attention
+    x = jnp.zeros((1, 8, 2, 16), jnp.float32)
+    pos = jnp.arange(8, dtype=jnp.int32)
+    with runtime.pallas_enabled(), pytest.raises(TypeError, match="window"):
+        attention.flash_attention(x, x, x, q_positions=pos, kv_positions=pos,
+                                  window=jnp.int32(4))
+
+
+@pytest.mark.parametrize("arch", ["tiny", "mamba2-1.3b"])
+def test_model_forward_through_kernels_matches_jnp(tiny_cfg, arch):
+    """pallas_enabled() routes attention / the SSD scan through the
+    kernels (interpret mode here) without changing the model's output."""
+    import dataclasses
+    from repro.config import get_smoke
+    from repro.kernels import runtime
+    from repro.models import Model
+    cfg = tiny_cfg if arch == "tiny" else get_smoke(arch)
+    model = Model(dataclasses.replace(cfg, dtype="float32"))
+    params = model.init(KEY)
+    toks = jax.random.randint(KEY, (2, 64), 0, cfg.vocab_size, jnp.int32)
+    ref = model.forward_logits(params, {"tokens": toks})
+    with runtime.pallas_enabled():
+        out = model.forward_logits(params, {"tokens": toks})
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.config import MoEConfig, ParallelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_lib
 from repro.models.spec import init_params
 from repro.sharding import make_rules
@@ -23,7 +24,7 @@ def _setup(E=4, top_k=2, d=32, eff=64, capacity_factor=8.0):
 def test_gshard_matches_dense_with_ample_capacity():
     """With capacity >> tokens, the capacity-dispatch path is exact."""
     cfg, params, x = _setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, ParallelConfig())
     y_dense, aux_d = moe_lib.moe_dense(params, cfg, x, act="silu_glu",
                                        dtype=jnp.float32)
@@ -37,7 +38,7 @@ def test_gshard_matches_dense_with_ample_capacity():
 
 def test_tp_matches_dense_with_ample_capacity():
     cfg, params, x = _setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, ParallelConfig())
     y_dense, _ = moe_lib.moe_dense(params, cfg, x, act="silu_glu",
                                    dtype=jnp.float32)
@@ -63,7 +64,7 @@ def test_capacity_drops_fall_through_to_residual():
     """Tokens beyond capacity produce zero output (residual passthrough),
     never garbage."""
     cfg, params, x = _setup(capacity_factor=0.05)   # almost everything drops
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, ParallelConfig())
     with mesh:
         y, _ = moe_lib.moe_gshard(params, cfg, x, rules=rules,

@@ -10,6 +10,7 @@ import pytest
 
 from conftest import make_batch
 from repro.config import ModelConfig, ParallelConfig, TrainConfig
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.sharding import make_rules
 from repro.train.optimizer import adamw_init
@@ -17,7 +18,7 @@ from repro.train.train_step import compile_train_step
 
 
 def _step_result(cfg: ModelConfig, parallel: ParallelConfig):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg, parallel, make_rules(mesh, parallel))
     params = model.init(jax.random.PRNGKey(0))
     batch = make_batch(cfg, 2, 16)

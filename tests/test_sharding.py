@@ -7,6 +7,7 @@ from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ParallelConfig
+from repro.launch.mesh import make_mesh
 from repro.models.spec import ParamSpec
 from repro.sharding import data_axes, fsdp_axes, make_rules, tree_shardings
 
@@ -16,7 +17,7 @@ AXES = ["batch", "seq", "embed", "mlp", "heads", "kv_heads", "vocab",
 
 @pytest.fixture(scope="module")
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _flat_axes(spec: P) -> list:
@@ -55,7 +56,7 @@ def test_shard_spec_properties(mesh11, dims):
 def test_shard_spec_divisibility_synthetic():
     """On a fake big mesh table, non-dividing dims stay unsharded."""
     import dataclasses
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, ParallelConfig())
     # monkey-table: pretend the mesh axes were 16x16 for divisibility math
     big = dataclasses.replace(rules, mesh=rules.mesh)
@@ -64,11 +65,11 @@ def test_shard_spec_divisibility_synthetic():
 
 
 def test_zero_modes_fsdp_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert fsdp_axes(mesh, ParallelConfig(zero="none")) == ()
     assert fsdp_axes(mesh, ParallelConfig(zero="zero1")) == ()
     assert fsdp_axes(mesh, ParallelConfig(zero="zero3")) == ("data",)
-    mesh3 = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh3 = make_mesh((1, 1, 1), ("pod", "data", "model"))
     assert fsdp_axes(mesh3, ParallelConfig(zero="zero3")) == ("pod", "data")
     # hierarchical ZeRO: gather group bounded to the pod-local data axis
     assert fsdp_axes(mesh3, ParallelConfig(zero="zero3_hier")) == ("data",)
@@ -77,7 +78,7 @@ def test_zero_modes_fsdp_axes():
 
 def test_tree_shardings_cover_params(tiny_cfg):
     from repro.models import Model
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, ParallelConfig())
     model = Model(tiny_cfg)
     sh = tree_shardings(rules, model.specs())
@@ -85,3 +86,30 @@ def test_tree_shardings_cover_params(tiny_cfg):
         model.specs(), is_leaf=lambda x: isinstance(x, ParamSpec)))
     n_sh = len(jax.tree_util.tree_leaves(sh))
     assert n_specs == n_sh
+
+
+def test_host_mesh_axes_are_auto():
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+
+
+def test_model_init_places_shards_and_matches_eager_init(tiny_cfg):
+    """Model.init runs under jit with the rules' shardings and draws the
+    same values as the eager per-leaf init."""
+    from repro.models import Model
+    from repro.models.spec import init_params
+    mesh = make_mesh((1, 1), ("data", "model"))
+    rules = make_rules(mesh, ParallelConfig())
+    model = Model(tiny_cfg, ParallelConfig(), rules)
+    key = jax.random.PRNGKey(7)
+    got = model.init(key)
+    want = init_params(model.specs(), key)
+    for a, b, sh in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(
+                            tree_shardings(rules, model.specs()))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert a.sharding == sh
