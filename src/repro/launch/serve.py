@@ -32,6 +32,7 @@ from repro.models import Model
 from repro.serve import make_prefill, make_serve_step
 from repro.sharding import make_rules
 from repro.utils import logger
+from repro.utils.profiling import span
 
 
 @dataclasses.dataclass(frozen=True, slots=True)
@@ -110,13 +111,15 @@ class ServeSession:
         """Prompt pass; stores caches + first greedy token on the session."""
         tokens = batch["tokens"]
         t0 = time.time()
-        logits, caches = self._prefill_fn(self.params, batch)
-        logits.block_until_ready()
-        dt = time.time() - t0
-        self._caches = caches
-        self.logits = logits
-        self._tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self._pos = int(tokens.shape[1])
+        with span("serve.prefill", batch=int(tokens.shape[0]),
+                  prompt_len=int(tokens.shape[1])):
+            logits, caches = self._prefill_fn(self.params, batch)
+            logits.block_until_ready()
+            dt = time.time() - t0
+            self._caches = caches
+            self.logits = logits
+            self._tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            self._pos = int(tokens.shape[1])
         return ServeTimings("prefill", dt, int(tokens.shape[0]),
                             int(tokens.shape[0] * tokens.shape[1]))
 
@@ -133,17 +136,19 @@ class ServeSession:
         tok, logits = self._tok, self.logits
         out = []
         t0 = time.time()
-        for t in range(self._pos, self._pos + n_steps):
-            out.append(tok)
-            logits, self._caches = self._step_fn(self.params, self._caches,
-                                                 tok, jnp.int32(t))
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(tok)
-        dt = time.time() - t0
-        self._pos += n_steps
-        self._tok = tok
-        self.logits = logits
-        gen = jnp.stack(out, axis=1)
+        with span("serve.decode", batch=int(tok.shape[0]), start=self._pos,
+                  steps=n_steps):
+            for t in range(self._pos, self._pos + n_steps):
+                out.append(tok)
+                logits, self._caches = self._step_fn(
+                    self.params, self._caches, tok, jnp.int32(t))
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            jax.block_until_ready(tok)
+            dt = time.time() - t0
+            self._pos += n_steps
+            self._tok = tok
+            self.logits = logits
+            gen = jnp.stack(out, axis=1)
         return gen, ServeTimings("decode", dt, int(tok.shape[0]),
                                  int(tok.shape[0] * n_steps))
 
@@ -169,8 +174,9 @@ class ServeSession:
         self._pos = 0
         self.logits = None
         t0 = time.time()
-        self.params = self.model.init(jax.random.PRNGKey(self._seed))
-        jax.block_until_ready(self.params)
+        with span("serve.restart"):
+            self.params = self.model.init(jax.random.PRNGKey(self._seed))
+            jax.block_until_ready(self.params)
         dt = time.time() - t0
         return ServeTimings("restart", dt, 0, 0)
 

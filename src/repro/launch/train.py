@@ -36,6 +36,7 @@ from repro.sharding import make_rules
 from repro.train.optimizer import adamw_abstract, adamw_init
 from repro.train.train_step import compile_train_step
 from repro.utils import logger
+from repro.utils.profiling import span
 
 
 @dataclasses.dataclass
@@ -111,44 +112,51 @@ class Trainer:
         self.detector.reset_after_rollback(ctx.start_step)
         step = ctx.start_step
         while step < self.total_steps:
-            data_step, batch = state.loader.next()
-            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-            state.params, state.opt, metrics = self.step_fn(
-                state.params, state.opt, batch)
-            loss = float(metrics["loss"])
-            # injected anomalies (benchmarks / example demos)
-            if data_step in self.spike_schedule and not self._skipped(state, data_step):
-                loss += self.spike_schedule[data_step]
-            step += 1
-            self.history.append((step, loss))
-            if step % self.log_every == 0:
-                logger.info("step %d loss %.4f lr %.2e", step, loss,
-                            float(metrics["lr"]))
-            ev = self.detector.update(step, loss,
-                                      self.ckpt.available_steps() or
-                                      list(self.ckpt.ram_cache))
-            if ev is not None:
-                raise SpikeInterrupt(ev)
-            if step % self.ckpt_every == 0:
-                stall = self.ckpt.save_async(
-                    step, (state.params, state.opt),
-                    extra={"data_step": state.loader.step,
-                           "skip_ranges": state.loader.skip_ranges})
-                logger.debug("ckpt %d stall %.1fms", step, stall * 1e3)
-            if self.host_time_fn is not None and self.fleet is not None:
-                for host, t in self.host_time_fn(step).items():
-                    self.straggler.record(host, t)
-                slow = [h for h in self.straggler.stragglers()
-                        if h not in self.fleet.cordoned]
-                if slow:
-                    self.fleet.cordon(slow)
-                    logger.info("stragglers cordoned at step %d: %s",
-                                step, slow)
-            if step in self.fault_schedule and step not in self._fired:
-                self._fired.add(step)
-                from repro.core.ft.events import generate_log
-                ft = self.fault_schedule[step]
-                raise JobFailure(step, generate_log(ft, seed=step), truth=ft.name)
+            with span("trainer.data"):
+                data_step, batch = state.loader.next()
+                batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+            b, s = batch["tokens"].shape
+            with span("trainer.step", step_num=step, batch=b, seq_len=s):
+                state.params, state.opt, metrics = self.step_fn(
+                    state.params, state.opt, batch)
+            with span("trainer.sync"):
+                loss = float(metrics["loss"])
+            with span("trainer.host"):
+                # injected anomalies (benchmarks / example demos)
+                if (data_step in self.spike_schedule
+                        and not self._skipped(state, data_step)):
+                    loss += self.spike_schedule[data_step]
+                step += 1
+                self.history.append((step, loss))
+                if step % self.log_every == 0:
+                    logger.info("step %d loss %.4f lr %.2e", step, loss,
+                                float(metrics["lr"]))
+                ev = self.detector.update(step, loss,
+                                          self.ckpt.available_steps() or
+                                          list(self.ckpt.ram_cache))
+                if ev is not None:
+                    raise SpikeInterrupt(ev)
+                if step % self.ckpt_every == 0:
+                    with span("trainer.ckpt"):
+                        self.ckpt.save_async(
+                            step, (state.params, state.opt),
+                            extra={"data_step": state.loader.step,
+                                   "skip_ranges": state.loader.skip_ranges})
+                if self.host_time_fn is not None and self.fleet is not None:
+                    for host, t in self.host_time_fn(step).items():
+                        self.straggler.record(host, t)
+                    slow = [h for h in self.straggler.stragglers()
+                            if h not in self.fleet.cordoned]
+                    if slow:
+                        self.fleet.cordon(slow)
+                        logger.info("stragglers cordoned at step %d: %s",
+                                    step, slow)
+                if step in self.fault_schedule and step not in self._fired:
+                    self._fired.add(step)
+                    from repro.core.ft.events import generate_log
+                    ft = self.fault_schedule[step]
+                    raise JobFailure(step, generate_log(ft, seed=step),
+                                     truth=ft.name)
         return step
 
     def _skipped(self, state: TrainerState, data_step: int) -> bool:

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.config import AttentionConfig
 from repro.models.layers import apply_rope
 from repro.models.spec import ParamSpec
+from repro.utils.profiling import scope
 
 Params = Any
 NEG_INF = -2.0 ** 30  # large-but-finite; avoids NaNs for fully-masked rows
@@ -279,15 +280,17 @@ def gqa_decode(params: Params, cfg: AttentionConfig, x: jax.Array,
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
     slot = jnp.mod(cur_index, cache_len)
-    new_cache = {
-        "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                          (0, slot, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                          (0, slot, 0, 0)),
-        "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (0, slot)),
-    }
-    out = attention_ref(q, new_cache["k"].astype(dtype),
-                        new_cache["v"].astype(dtype),
+    with scope("kv_cache"):
+        new_cache = {
+            "k": jax.lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0)),
+            "v": jax.lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0)),
+            "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (0, slot)),
+        }
+        k_all = new_cache["k"].astype(dtype)
+        v_all = new_cache["v"].astype(dtype)
+    out = attention_ref(q, k_all, v_all,
                         q_positions=pos, kv_positions=new_cache["pos"],
                         window=window, causal=True,
                         softcap=cfg.logit_softcap)
@@ -384,28 +387,30 @@ def mla_decode(params: Params, cfg: AttentionConfig, x: jax.Array,
     q_nope, q_rope = _mla_q(params, cfg, x, pos, dtype)          # (B,1,H,*)
     ckv_new, k_rope_new = _mla_latent(params, cfg, x, pos, dtype)
     slot = jnp.mod(cur_index, cache_len)
-    cache = {
-        "ckv": jax.lax.dynamic_update_slice(
-            cache["ckv"], ckv_new.astype(cache["ckv"].dtype), (0, slot, 0)),
-        "k_rope": jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
-            (0, slot, 0)),
-        "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (0, slot)),
-    }
+    with scope("kv_cache"):
+        cache = {
+            "ckv": jax.lax.dynamic_update_slice(
+                cache["ckv"], ckv_new.astype(cache["ckv"].dtype),
+                (0, slot, 0)),
+            "k_rope": jax.lax.dynamic_update_slice(
+                cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
+                (0, slot, 0)),
+            "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (0, slot)),
+        }
+        ckv = cache["ckv"].astype(dtype)
+        k_rope = cache["k_rope"].astype(dtype)
     # absorb w_uk into the query: q_lat[b,1,h,r]
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, params["w_uk"].astype(dtype))
-    s = (jnp.einsum("bqhr,bsr->bhqs", q_lat, cache["ckv"].astype(dtype),
+    s = (jnp.einsum("bqhr,bsr->bhqs", q_lat, ckv,
                     preferred_element_type=jnp.float32)
-         + jnp.einsum("bqhk,bsk->bhqs", q_rope,
-                      cache["k_rope"].astype(dtype),
+         + jnp.einsum("bqhk,bsk->bhqs", q_rope, k_rope,
                       preferred_element_type=jnp.float32))
     s = s * ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5)
     kv_pos = cache["pos"][:, None, None, :]                  # (B,1,1,S)
     ok = (kv_pos >= 0) & (kv_pos <= cur_index)
     s = jnp.where(ok, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhqs,bsr->bqhr", p.astype(dtype),
-                     cache["ckv"].astype(dtype))
+    ctx = jnp.einsum("bhqs,bsr->bqhr", p.astype(dtype), ckv)
     out = jnp.einsum("bqhr,rhv->bqhv", ctx, params["w_uv"].astype(dtype))
     y = jnp.einsum("bqhv,hvd->bqd", out, params["wo"].astype(dtype))
     return y, cache

@@ -24,6 +24,7 @@ from repro.models import mamba as mamba_lib
 from repro.models import moe as moe_lib
 from repro.models.spec import ParamSpec, abstract_params, init_params, stack_specs
 from repro.sharding import Rules, constrain, tree_shardings
+from repro.utils.profiling import scope
 
 Params = Any
 
@@ -126,28 +127,34 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
             return constrain(y, rules, "batch", "seq", None)
         return y
 
-    x = L.rmsnorm(params["ln1"], h, cfg.norm_eps)
+    with scope("norm"):
+        x = L.rmsnorm(params["ln1"], h, cfg.norm_eps)
     if plan.mixer == "attn":
         acfg = cfg.attention
         if mode == "decode":
-            if acfg.kind == "mla":
-                y, kv = attn_lib.mla_decode(params["attn"], acfg, x,
-                                            cache["kv"], cur_index,
-                                            dtype=dtype)
-            else:
-                y, kv = attn_lib.gqa_decode(params["attn"], acfg, x,
-                                            cache["kv"], cur_index,
-                                            window=plan.window, dtype=dtype)
+            with scope("attn"):
+                if acfg.kind == "mla":
+                    y, kv = attn_lib.mla_decode(params["attn"], acfg, x,
+                                                cache["kv"], cur_index,
+                                                dtype=dtype)
+                else:
+                    y, kv = attn_lib.gqa_decode(params["attn"], acfg, x,
+                                                cache["kv"], cur_index,
+                                                window=plan.window,
+                                                dtype=dtype)
             new_cache["kv"] = kv
         else:
-            if acfg.kind == "mla":
-                y = attn_lib.mla_forward(params["attn"], acfg, x, positions,
-                                         dtype=dtype, block_kv=cfg.attn_block_kv)
-            else:
-                y = attn_lib.gqa_forward(params["attn"], acfg, x, positions,
-                                         window=plan.window, dtype=dtype,
-                                         block_kv=cfg.attn_block_kv,
-                                         causal=causal)
+            with scope("attn"):
+                if acfg.kind == "mla":
+                    y = attn_lib.mla_forward(params["attn"], acfg, x,
+                                             positions, dtype=dtype,
+                                             block_kv=cfg.attn_block_kv)
+                else:
+                    y = attn_lib.gqa_forward(params["attn"], acfg, x,
+                                             positions, window=plan.window,
+                                             dtype=dtype,
+                                             block_kv=cfg.attn_block_kv,
+                                             causal=causal)
             if mode == "prefill":
                 # ring-buffer length: the window (SWA) or the decode horizon
                 # (defaults to the model max; serving passes the actual
@@ -155,64 +162,76 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
                 horizon = max_cache_len or cfg.max_seq_len
                 cache_len = min(_cache_len(cfg, plan),
                                 max(horizon, x.shape[1]))
-                if acfg.kind == "mla":
-                    new_cache["kv"] = attn_lib.mla_prefill_cache(
-                        params["attn"], acfg, x, positions, cache_len, dtype)
-                else:
-                    new_cache["kv"] = attn_lib.gqa_prefill_cache(
-                        params["attn"], acfg, x, positions, cache_len, dtype)
+                with scope("kv_cache"):
+                    if acfg.kind == "mla":
+                        new_cache["kv"] = attn_lib.mla_prefill_cache(
+                            params["attn"], acfg, x, positions, cache_len,
+                            dtype)
+                    else:
+                        new_cache["kv"] = attn_lib.gqa_prefill_cache(
+                            params["attn"], acfg, x, positions, cache_len,
+                            dtype)
     else:
-        if mode == "decode":
-            y, ssm_cache = mamba_lib.mamba_decode(
-                params["mamba"], cfg.ssm, x, cache["ssm"], d_model=cfg.d_model,
-                dtype=dtype, norm_eps=cfg.norm_eps)
-            new_cache["ssm"] = ssm_cache
-        elif mode == "prefill":
-            y, ssm_cache = mamba_lib.mamba_forward(
-                params["mamba"], cfg.ssm, x, d_model=cfg.d_model, dtype=dtype,
-                norm_eps=cfg.norm_eps, return_state=True)
-            new_cache["ssm"] = ssm_cache
-        else:
-            y = mamba_lib.mamba_forward(params["mamba"], cfg.ssm, x,
-                                        d_model=cfg.d_model, dtype=dtype,
-                                        norm_eps=cfg.norm_eps)
+        with scope("ssm"):
+            if mode == "decode":
+                y, ssm_cache = mamba_lib.mamba_decode(
+                    params["mamba"], cfg.ssm, x, cache["ssm"],
+                    d_model=cfg.d_model, dtype=dtype, norm_eps=cfg.norm_eps)
+                new_cache["ssm"] = ssm_cache
+            elif mode == "prefill":
+                y, ssm_cache = mamba_lib.mamba_forward(
+                    params["mamba"], cfg.ssm, x, d_model=cfg.d_model,
+                    dtype=dtype, norm_eps=cfg.norm_eps, return_state=True)
+                new_cache["ssm"] = ssm_cache
+            else:
+                y = mamba_lib.mamba_forward(params["mamba"], cfg.ssm, x,
+                                            d_model=cfg.d_model, dtype=dtype,
+                                            norm_eps=cfg.norm_eps)
     h = h + _seq_shard(y)
 
     if plan.cross_attn:
-        xq = L.rmsnorm(params["ln_cross"], h, cfg.norm_eps)
+        with scope("norm"):
+            xq = L.rmsnorm(params["ln_cross"], h, cfg.norm_eps)
         acfg = dataclasses.replace(cfg.attention, use_rope=False)
-        if mode == "decode":
-            k, v = cache["cross_k"], cache["cross_v"]
-            q = jnp.einsum("bsd,dhk->bshk", xq, params["cross"]["wq"].astype(dtype))
-            o = attn_lib.attention_ref(
-                q, k.astype(dtype), v.astype(dtype),
-                q_positions=jnp.zeros((xq.shape[0], 1), jnp.int32),
-                kv_positions=jnp.zeros((k.shape[0], k.shape[1]), jnp.int32),
-                causal=False)
-            y = jnp.einsum("bshk,hkd->bsd", o,
-                           params["cross"]["wo"].astype(dtype))
-            new_cache["cross_k"], new_cache["cross_v"] = k, v
-        else:
-            k, v = attn_lib.gqa_kv(params["cross"], acfg, enc_out,
-                                   enc_positions, dtype)
-            y = attn_lib.gqa_forward(params["cross"], acfg, xq, positions,
-                                     window=0, dtype=dtype,
-                                     block_kv=cfg.attn_block_kv,
-                                     kv_override=(k, v, enc_positions),
-                                     causal=False)
-            if mode == "prefill":
+        with scope("attn"):
+            if mode == "decode":
+                k, v = cache["cross_k"], cache["cross_v"]
+                q = jnp.einsum("bsd,dhk->bshk", xq,
+                               params["cross"]["wq"].astype(dtype))
+                o = attn_lib.attention_ref(
+                    q, k.astype(dtype), v.astype(dtype),
+                    q_positions=jnp.zeros((xq.shape[0], 1), jnp.int32),
+                    kv_positions=jnp.zeros((k.shape[0], k.shape[1]),
+                                           jnp.int32),
+                    causal=False)
+                y = jnp.einsum("bshk,hkd->bsd", o,
+                               params["cross"]["wo"].astype(dtype))
                 new_cache["cross_k"], new_cache["cross_v"] = k, v
+            else:
+                k, v = attn_lib.gqa_kv(params["cross"], acfg, enc_out,
+                                       enc_positions, dtype)
+                y = attn_lib.gqa_forward(params["cross"], acfg, xq, positions,
+                                         window=0, dtype=dtype,
+                                         block_kv=cfg.attn_block_kv,
+                                         kv_override=(k, v, enc_positions),
+                                         causal=False)
+                if mode == "prefill":
+                    new_cache["cross_k"], new_cache["cross_v"] = k, v
         h = h + y
 
     if plan.mlp == "none":
         return h, new_cache, aux
-    x2 = L.rmsnorm(params["ln2"], h, cfg.norm_eps)
+    with scope("norm"):
+        x2 = L.rmsnorm(params["ln2"], h, cfg.norm_eps)
     if plan.mlp == "moe":
-        y, aux = moe_lib.moe_forward(params["moe"], cfg, x2, rules=rules,
-                                     parallel=parallel,
-                                     decode=(mode == "decode"), dtype=dtype)
+        with scope("moe"):
+            y, aux = moe_lib.moe_forward(params["moe"], cfg, x2, rules=rules,
+                                         parallel=parallel,
+                                         decode=(mode == "decode"),
+                                         dtype=dtype)
     else:
-        y = L.mlp(params["mlp"], x2, cfg.mlp_act, dtype)
+        with scope("mlp"):
+            y = L.mlp(params["mlp"], x2, cfg.mlp_act, dtype)
     return h + _seq_shard(y), new_cache, aux
 
 
@@ -291,19 +310,21 @@ class Model:
 
     def _embed_inputs(self, params: Params, batch: dict):
         """Returns (h, positions, loss_weights)."""
-        cfg = self.cfg
-        tok = batch["tokens"]
-        h = L.embed(params["embed"], tok, self.dtype, cfg.d_model)
-        weights = batch.get("weights")
-        if weights is None:
-            weights = jnp.ones(tok.shape, jnp.float32)
-        if cfg.frontend == "patch_stub":
-            patches = batch["patches"].astype(self.dtype)   # (B, Np, d)
-            h = jnp.concatenate([patches, h], axis=1)
-            weights = jnp.concatenate(
-                [jnp.zeros(patches.shape[:2], jnp.float32), weights], axis=1)
-        positions = jnp.arange(h.shape[1], dtype=jnp.int32)
-        return h, positions, weights
+        with scope("embed"):
+            cfg = self.cfg
+            tok = batch["tokens"]
+            h = L.embed(params["embed"], tok, self.dtype, cfg.d_model)
+            weights = batch.get("weights")
+            if weights is None:
+                weights = jnp.ones(tok.shape, jnp.float32)
+            if cfg.frontend == "patch_stub":
+                patches = batch["patches"].astype(self.dtype)   # (B, Np, d)
+                h = jnp.concatenate([patches, h], axis=1)
+                weights = jnp.concatenate(
+                    [jnp.zeros(patches.shape[:2], jnp.float32), weights],
+                    axis=1)
+            positions = jnp.arange(h.shape[1], dtype=jnp.int32)
+            return h, positions, weights
 
     # -- stacks -------------------------------------------------------------
 
@@ -340,20 +361,21 @@ class Model:
                 body = jax.checkpoint(body, policy=policy,
                                       prevent_cse=False)
 
-            if parallel.scan_layers:
-                (h, aux_total), nc_stack = jax.lax.scan(
-                    body, (h, aux_total), (p_stack, c_stack))
-            else:
-                # unrolled python loop (cost-analysis calibration + small
-                # models): identical math, no while-loop in the HLO
-                ncs_all = []
-                for r in range(seg.repeat):
-                    xs = jax.tree_util.tree_map(lambda x, _r=r: x[_r],
-                                                (p_stack, c_stack))
-                    (h, aux_total), nc = body((h, aux_total), xs)
-                    ncs_all.append(nc)
-                nc_stack = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *ncs_all)
+            with scope("layers"):
+                if parallel.scan_layers:
+                    (h, aux_total), nc_stack = jax.lax.scan(
+                        body, (h, aux_total), (p_stack, c_stack))
+                else:
+                    # unrolled python loop (cost-analysis calibration + small
+                    # models): identical math, no while-loop in the HLO
+                    ncs_all = []
+                    for r in range(seg.repeat):
+                        xs = jax.tree_util.tree_map(lambda x, _r=r: x[_r],
+                                                    (p_stack, c_stack))
+                        (h, aux_total), nc = body((h, aux_total), xs)
+                        ncs_all.append(nc)
+                    nc_stack = jax.tree_util.tree_map(
+                        lambda *xs: jnp.stack(xs), *ncs_all)
             new_caches.append(nc_stack)
         return h, new_caches, aux_total
 
@@ -376,7 +398,9 @@ class Model:
             enc_h, _, _ = self._run_segments(
                 params["enc_segments"], self.enc_segments, enc_h,
                 positions=enc_pos, mode="train", causal=False)
-            enc_out = L.rmsnorm(params["enc_final_norm"], enc_h, cfg.norm_eps)
+            with scope("norm"):
+                enc_out = L.rmsnorm(params["enc_final_norm"], enc_h,
+                                    cfg.norm_eps)
             enc_positions = enc_pos
             h = h + params["dec_pos"][positions].astype(self.dtype)
         if self.rules is not None:
@@ -385,14 +409,16 @@ class Model:
             params["segments"], self.segments, h, positions=positions,
             mode=mode, enc_out=enc_out, enc_positions=enc_positions,
             max_cache_len=max_cache_len)
-        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+        with scope("norm"):
+            h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
         return h, weights, (caches if mode == "prefill" else None), aux
 
     def logits_fn(self, params: Params):
         cfg = self.cfg
         def fn(h):
-            return L.lm_head(params.get("lm_head"), params["embed"], h,
-                             cfg.tie_embeddings, self.dtype)
+            with scope("lm_head"):
+                return L.lm_head(params.get("lm_head"), params["embed"], h,
+                                 cfg.tie_embeddings, self.dtype)
         return fn
 
     def loss(self, params: Params, batch: dict):
@@ -404,8 +430,9 @@ class Model:
             pad = h.shape[1] - labels.shape[1]
             labels = jnp.pad(labels, ((0, 0), (pad, 0)))
         z = getattr(self, "z_loss", 1e-4)
-        total, wsum = L.softmax_xent_chunked(
-            self.logits_fn(params), h, labels, weights, z_loss=z)
+        with scope("loss"):
+            total, wsum = L.softmax_xent_chunked(
+                self.logits_fn(params), h, labels, weights, z_loss=z)
         xent = total / jnp.maximum(wsum, 1.0)
         loss = xent + aux
         return loss, {"loss": loss, "xent": xent, "aux": aux,
@@ -434,7 +461,9 @@ class Model:
         Returns (logits (B, V), new_caches).
         """
         cfg = self.cfg
-        h = L.embed(params["embed"], tokens[:, None], self.dtype, cfg.d_model)
+        with scope("embed"):
+            h = L.embed(params["embed"], tokens[:, None], self.dtype,
+                        cfg.d_model)
         if cfg.family == "audio":
             pos_e = jax.lax.dynamic_slice_in_dim(params["dec_pos"],
                                                  cur_index, 1, axis=0)
@@ -442,7 +471,8 @@ class Model:
         h, new_caches, _ = self._run_segments(
             params["segments"], self.segments, h, positions=None,
             mode="decode", caches=caches, cur_index=cur_index)
-        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+        with scope("norm"):
+            h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = self.logits_fn(params)(h)
         return logits[:, 0], new_caches
 

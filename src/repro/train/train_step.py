@@ -24,6 +24,7 @@ from repro.sharding import Rules, make_rules, tree_shardings
 from repro.train.optimizer import (AdamState, adamw_abstract, adamw_init,
                                    adamw_update, compress_grads,
                                    compressor_init)
+from repro.utils.profiling import scope
 
 Params = Any
 
@@ -140,8 +141,9 @@ def make_train_step(model: Model, tcfg: TrainConfig,
             (loss, metrics), grads = grad_fn(params, batch)
             grads = _pin(grads)
 
-        new_params, new_opt, opt_metrics = adamw_update(
-            grads, opt_state, params, tcfg)
+        with scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                grads, opt_state, params, tcfg)
         metrics.update(opt_metrics)
         return new_params, new_opt, metrics
 
