@@ -266,12 +266,12 @@ def gqa_prefill_cache(params: Params, cfg: AttentionConfig, x: jax.Array,
     }
 
 
-def gqa_decode(params: Params, cfg: AttentionConfig, x: jax.Array,
-               cache: dict, cur_index: jax.Array, *,
-               window: jax.Array | int, dtype: Any) -> tuple[jax.Array, dict]:
-    """One-token decode. x: (B, 1, d); cur_index: scalar absolute position."""
+def gqa_decode_qkv(params: Params, cfg: AttentionConfig, x: jax.Array,
+                   cur_index: jax.Array, *, dtype: Any) -> tuple[jax.Array, dict]:
+    """One decode token's query and cache entries. x: (B, 1, d);
+    cur_index: scalar absolute position. Returns q (B, 1, H, D) and the
+    entries k, v (B, 1, KV, D) and pos (B, 1) for its ring-buffer slot."""
     B = x.shape[0]
-    cache_len = cache["k"].shape[1]
     pos = jnp.full((B, 1), cur_index, jnp.int32)
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dtype))
@@ -279,23 +279,23 @@ def gqa_decode(params: Params, cfg: AttentionConfig, x: jax.Array,
     if cfg.use_rope:
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
-    slot = jnp.mod(cur_index, cache_len)
+    return q, {"k": k, "v": v, "pos": pos}
+
+
+def gqa_decode_attend(params: Params, cfg: AttentionConfig, q: jax.Array,
+                      cache: dict, cur_index: jax.Array, *,
+                      window: jax.Array | int, dtype: Any) -> jax.Array:
+    """One decode token's attention over its layer's ring buffer ``cache``
+    (k, v: (B, L, KV, D); pos: (B, L)), holding the token's own entries."""
+    B = q.shape[0]
     with scope("kv_cache"):
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0)),
-            "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (0, slot)),
-        }
-        k_all = new_cache["k"].astype(dtype)
-        v_all = new_cache["v"].astype(dtype)
+        k_all = cache["k"].astype(dtype)
+        v_all = cache["v"].astype(dtype)
     out = attention_ref(q, k_all, v_all,
-                        q_positions=pos, kv_positions=new_cache["pos"],
-                        window=window, causal=True,
-                        softcap=cfg.logit_softcap)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dtype))
-    return y, new_cache
+                        q_positions=jnp.full((B, 1), cur_index, jnp.int32),
+                        kv_positions=cache["pos"], window=window,
+                        causal=True, softcap=cfg.logit_softcap)
+    return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -376,27 +376,25 @@ def mla_prefill_cache(params: Params, cfg: AttentionConfig, x: jax.Array,
     }
 
 
-def mla_decode(params: Params, cfg: AttentionConfig, x: jax.Array,
-               cache: dict, cur_index: jax.Array, *,
-               dtype: Any) -> tuple[jax.Array, dict]:
-    """Absorbed-weight decode: attend in the 512-d latent space directly —
-    the compressed-KV insight of MLA; no per-head K/V is ever materialized."""
+def mla_decode_qkv(params: Params, cfg: AttentionConfig, x: jax.Array,
+                   cur_index: jax.Array, *, dtype: Any) -> tuple[tuple, dict]:
+    """One decode token's query parts (q_nope, q_rope: (B, 1, H, *)) and
+    latent cache entries ckv (B, 1, r), k_rope (B, 1, rope), pos (B, 1)."""
     B = x.shape[0]
-    cache_len = cache["ckv"].shape[1]
     pos = jnp.full((B, 1), cur_index, jnp.int32)
-    q_nope, q_rope = _mla_q(params, cfg, x, pos, dtype)          # (B,1,H,*)
-    ckv_new, k_rope_new = _mla_latent(params, cfg, x, pos, dtype)
-    slot = jnp.mod(cur_index, cache_len)
+    q = _mla_q(params, cfg, x, pos, dtype)
+    ckv, k_rope = _mla_latent(params, cfg, x, pos, dtype)
+    return q, {"ckv": ckv, "k_rope": k_rope, "pos": pos}
+
+
+def mla_decode_attend(params: Params, cfg: AttentionConfig, q: tuple,
+                      cache: dict, cur_index: jax.Array, *,
+                      dtype: Any) -> jax.Array:
+    """Absorbed-weight decode: attend in the 512-d latent space directly —
+    the compressed-KV insight of MLA; no per-head K/V is ever materialized.
+    ``cache`` is the layer's latent ring buffer, holding this token's."""
+    q_nope, q_rope = q
     with scope("kv_cache"):
-        cache = {
-            "ckv": jax.lax.dynamic_update_slice(
-                cache["ckv"], ckv_new.astype(cache["ckv"].dtype),
-                (0, slot, 0)),
-            "k_rope": jax.lax.dynamic_update_slice(
-                cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
-                (0, slot, 0)),
-            "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (0, slot)),
-        }
         ckv = cache["ckv"].astype(dtype)
         k_rope = cache["k_rope"].astype(dtype)
     # absorb w_uk into the query: q_lat[b,1,h,r]
@@ -412,5 +410,4 @@ def mla_decode(params: Params, cfg: AttentionConfig, x: jax.Array,
     p = jax.nn.softmax(s, axis=-1)
     ctx = jnp.einsum("bhqs,bsr->bqhr", p.astype(dtype), ckv)
     out = jnp.einsum("bqhr,rhv->bqhv", ctx, params["w_uv"].astype(dtype))
-    y = jnp.einsum("bqhv,hvd->bqd", out, params["wo"].astype(dtype))
-    return y, cache
+    return jnp.einsum("bqhv,hvd->bqd", out, params["wo"].astype(dtype))

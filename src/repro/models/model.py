@@ -11,6 +11,7 @@ single-layer segment. Scanning keeps HLO size (and hence compile time for
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -113,9 +114,14 @@ def _layer_specs(cfg: ModelConfig, plan: LayerPlan) -> dict:
 def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
                  rules: Optional[Rules], plan: LayerPlan, params: Params,
                  h: jax.Array, *, positions, dtype, mode: str,
-                 cache: Optional[dict], cur_index, enc_out, enc_positions,
-                 causal: bool = True, max_cache_len: int = 0):
-    """Returns (h, new_cache, aux)."""
+                 cache: Optional[dict], layer, cur_index, enc_out,
+                 enc_positions, causal: bool = True, max_cache_len: int = 0):
+    """Returns (h, new_cache, aux).
+
+    In decode, ``cache`` holds the stacked caches of this layer's pattern
+    position, read-only, and ``layer`` indexes them; new_cache holds this
+    step's entries for the layer (``_store_step`` writes them). Otherwise
+    new_cache is the layer's own cache (prefill) or empty (train)."""
     aux = jnp.float32(0.0)
     new_cache: dict = {}
 
@@ -133,16 +139,24 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
         acfg = cfg.attention
         if mode == "decode":
             with scope("attn"):
-                if acfg.kind == "mla":
-                    y, kv = attn_lib.mla_decode(params["attn"], acfg, x,
-                                                cache["kv"], cur_index,
-                                                dtype=dtype)
+                mla = acfg.kind == "mla"
+                project = (attn_lib.mla_decode_qkv if mla
+                           else attn_lib.gqa_decode_qkv)
+                q, entries = project(params["attn"], acfg, x, cur_index,
+                                     dtype=dtype)
+                kv = cache["kv"]
+                slot = jnp.mod(cur_index, kv["pos"].shape[2])
+                view = _layer_with_slot(kv, entries, layer, slot)
+                if mla:
+                    y = attn_lib.mla_decode_attend(params["attn"], acfg, q,
+                                                   view, cur_index,
+                                                   dtype=dtype)
                 else:
-                    y, kv = attn_lib.gqa_decode(params["attn"], acfg, x,
-                                                cache["kv"], cur_index,
-                                                window=plan.window,
-                                                dtype=dtype)
-            new_cache["kv"] = kv
+                    y = attn_lib.gqa_decode_attend(params["attn"], acfg, q,
+                                                   view, cur_index,
+                                                   window=plan.window,
+                                                   dtype=dtype)
+            new_cache["kv"] = entries
         else:
             with scope("attn"):
                 if acfg.kind == "mla":
@@ -174,10 +188,10 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
     else:
         with scope("ssm"):
             if mode == "decode":
-                y, ssm_cache = mamba_lib.mamba_decode(
-                    params["mamba"], cfg.ssm, x, cache["ssm"],
-                    d_model=cfg.d_model, dtype=dtype, norm_eps=cfg.norm_eps)
-                new_cache["ssm"] = ssm_cache
+                y, new_cache["ssm"] = mamba_lib.mamba_decode(
+                    params["mamba"], cfg.ssm, x,
+                    _layer_of(cache["ssm"], layer), d_model=cfg.d_model,
+                    dtype=dtype, norm_eps=cfg.norm_eps)
             elif mode == "prefill":
                 y, ssm_cache = mamba_lib.mamba_forward(
                     params["mamba"], cfg.ssm, x, d_model=cfg.d_model,
@@ -195,7 +209,8 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
         acfg = dataclasses.replace(cfg.attention, use_rope=False)
         with scope("attn"):
             if mode == "decode":
-                k, v = cache["cross_k"], cache["cross_v"]
+                k = _layer_of(cache["cross_k"], layer)
+                v = _layer_of(cache["cross_v"], layer)
                 q = jnp.einsum("bsd,dhk->bshk", xq,
                                params["cross"]["wq"].astype(dtype))
                 o = attn_lib.attention_ref(
@@ -206,7 +221,6 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
                     causal=False)
                 y = jnp.einsum("bshk,hkd->bsd", o,
                                params["cross"]["wo"].astype(dtype))
-                new_cache["cross_k"], new_cache["cross_v"] = k, v
             else:
                 k, v = attn_lib.gqa_kv(params["cross"], acfg, enc_out,
                                        enc_positions, dtype)
@@ -233,6 +247,95 @@ def _apply_layer(cfg: ModelConfig, parallel: Optional[ParallelConfig],
         with scope("mlp"):
             y = L.mlp(params["mlp"], x2, cfg.mlp_act, dtype)
     return h + _seq_shard(y), new_cache, aux
+
+
+def _layer_of(stack, layer):
+    """Layer ``layer`` of stacked cache leaves (a leaf or a dict of them)."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.dynamic_index_in_dim(x, layer, keepdims=False),
+        stack)
+
+
+def _layer_with_slot(stack: dict, entries: dict, layer, slot) -> dict:
+    """Layer ``layer`` of a stacked ring buffer as it reads once this
+    step's ``entries`` (batch first, one slot long) are at ``slot``. The
+    stack is the step's input, unwritten (``_store_step`` writes after the
+    layers); the select on the read fuses into the attention's dots."""
+    with scope("kv_cache"):
+        view = _layer_of(stack, layer)
+        hit = jnp.arange(stack["pos"].shape[2]) == slot
+
+        def merge(old, new):
+            at = hit.reshape((1, -1) + (1,) * (old.ndim - 2))
+            return jnp.where(at, new.astype(old.dtype), old)
+        return {n: merge(view[n], entries[n]) for n in view}
+
+
+# TPU vector registers are 128 lanes wide; a tile of an array spans 128
+# elements of its minor dimension
+_LANES = 128
+
+
+def _write_slot(x, u, slot, block: int):
+    """Stacked ring buffer ``x`` (layers, B, L, ...) with every layer's
+    entry ``u`` (layers, B, 1, ...) at ``slot``, written in place.
+
+    With ``block`` > 1 the write covers the ``block`` slots from the
+    multiple of ``block`` at or below ``slot``: ``u`` at the slot, ``x``'s
+    own values around it. A narrow entry (a minor width that is no
+    multiple of the lanes, like smollm-360m's heads of 64) makes the TPU
+    lay the slots out on the lanes; a one-slot write then touches each
+    tile one lane at a time, while a 128-slot block writes whole tiles."""
+    if block == 1:
+        return jax.lax.dynamic_update_slice(
+            x, u, (0, 0, slot) + (0,) * (x.ndim - 3))
+    base = slot & -block
+    start = (0, 0, base) + (0,) * (x.ndim - 3)
+    old = jax.lax.dynamic_slice(x, start, x.shape[:2] + (block,) + x.shape[3:],
+                                allow_negative_indices=False)
+    hit = base + jnp.arange(block) == slot
+    new = jnp.where(hit.reshape((1, 1, block) + (1,) * (x.ndim - 3)), u, old)
+    return jax.lax.dynamic_update_slice(x, new, start,
+                                        allow_negative_indices=False)
+
+
+def _store_step(stacks: tuple, steps: tuple, cur_index,
+                rules: Optional[Rules]) -> tuple:
+    """A segment's caches after one decode step, from the stacked caches
+    the step read and the entries its layers returned (stacked likewise).
+
+    A ring buffer takes the step's slot of every layer in one in-place
+    write; an SSM state is replaced whole; cross-attention memos are
+    read-only. The write comes after the layer loop, not inside it: with
+    the stacks carried through the loop and written there, the TPU
+    compiler lays the carry out for the one-slot write and copies the
+    whole cache into and out of the loop each step.
+
+    A narrow entry is written as a lane-aligned block of slots
+    (``_write_slot``), unless the slots are split over devices: a block
+    there would cross shards."""
+    out = []
+    for stack, step in zip(stacks, steps):
+        new = dict(stack)
+        if "kv" in stack:
+            shape = stack["kv"]["pos"].shape[1:]            # (B, L)
+            slot = jnp.mod(cur_index, shape[1])
+            split = rules is not None and rules.sharding(
+                shape, ("batch", "kv_seq")).shard_shape(shape)[1] < shape[1]
+
+            def write(x, u):
+                narrow = x.ndim > 3 and x.shape[-1] % _LANES != 0
+                block = (math.gcd(x.shape[2], _LANES)
+                         if narrow and not split else 1)
+                return _write_slot(x, u.astype(x.dtype), slot, block)
+            with scope("attn"), scope("kv_cache"):
+                new["kv"] = jax.tree_util.tree_map(write, stack["kv"],
+                                                   step["kv"])
+        if "ssm" in stack:
+            new["ssm"] = jax.tree_util.tree_map(
+                lambda x, u: u.astype(x.dtype), stack["ssm"], step["ssm"])
+        out.append(new)
+    return tuple(out)
 
 
 def _cache_len(cfg: ModelConfig, plan: LayerPlan) -> int:
@@ -331,24 +434,32 @@ class Model:
     def _run_segments(self, params_segs, segments, h, *, positions, mode,
                       caches=None, cur_index=None, enc_out=None,
                       enc_positions=None, causal=True, max_cache_len=0):
-        """Apply all segments; returns (h, new_caches, aux_total)."""
+        """Apply all segments; returns (h, new_caches, aux_total).
+
+        A segment's stacked weights are the scan's ``xs``; its layers'
+        outputs are the ``ys``: the caches prefill builds, or the entries
+        a decode step makes. Decode reads each layer's cache from the
+        segment's stacked caches as they came in, and writes the step's
+        slot of every layer after the scan (``_store_step``), in place: no
+        layer's cache is sliced out of the stack and restacked."""
         cfg, parallel, rules = self.cfg, self.parallel, self.rules
+        decode = mode == "decode"
         aux_total = jnp.float32(0.0)
         new_caches = []
         for si, seg in enumerate(segments):
             p_stack = params_segs[si]
-            c_stack = caches[si] if caches is not None else None
+            c_stack = caches[si] if decode else None
 
-            def body(carry, xs, _seg=seg):
+            def body(carry, xs, _seg=seg, _c=c_stack):
                 hh, aux = carry
-                p_slice, c_slice = xs
+                p_slice, layer = xs
                 ncs = []
                 for li, plan in enumerate(_seg.pattern):
-                    c = c_slice[li] if c_slice is not None else None
                     hh, nc, a = _apply_layer(
                         cfg, parallel, rules, plan, p_slice[li], hh,
                         positions=positions, dtype=self.dtype, mode=mode,
-                        cache=c, cur_index=cur_index, enc_out=enc_out,
+                        cache=_c[li] if decode else None, layer=layer,
+                        cur_index=cur_index, enc_out=enc_out,
                         enc_positions=enc_positions, causal=causal,
                         max_cache_len=max_cache_len)
                     ncs.append(nc)
@@ -363,19 +474,24 @@ class Model:
 
             with scope("layers"):
                 if parallel.scan_layers:
+                    layers = jnp.arange(seg.repeat) if decode else None
                     (h, aux_total), nc_stack = jax.lax.scan(
-                        body, (h, aux_total), (p_stack, c_stack))
+                        body, (h, aux_total), (p_stack, layers))
                 else:
                     # unrolled python loop (cost-analysis calibration + small
                     # models): identical math, no while-loop in the HLO
                     ncs_all = []
                     for r in range(seg.repeat):
-                        xs = jax.tree_util.tree_map(lambda x, _r=r: x[_r],
-                                                    (p_stack, c_stack))
-                        (h, aux_total), nc = body((h, aux_total), xs)
+                        p_slice = jax.tree_util.tree_map(
+                            lambda x, _r=r: x[_r], p_stack)
+                        (h, aux_total), nc = body(
+                            (h, aux_total), (p_slice, r if decode else None))
                         ncs_all.append(nc)
                     nc_stack = jax.tree_util.tree_map(
                         lambda *xs: jnp.stack(xs), *ncs_all)
+                if decode:
+                    nc_stack = _store_step(c_stack, nc_stack, cur_index,
+                                           rules)
             new_caches.append(nc_stack)
         return h, new_caches, aux_total
 

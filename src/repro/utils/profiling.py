@@ -29,11 +29,16 @@ jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 SCOPES = (
     "embed",        # token embedding (and a frontend's inputs)
-    "layers",       # the layer stack: per-layer slicing and restacking of
-                    # weights, caches and saved activations; residual adds
+    "layers",       # the layer stack: per-layer slicing of weights, the
+                    # train step's saved activations and prefill's caches,
+                    # their restacking; residual adds. Decode's caches are
+                    # read in place, not sliced out
     "norm",         # RMSNorm calls of the model
     "attn",         # attention sublayer: projections and core
-    "kv_cache",     # KV ring-buffer writes and the cast of the cache read
+    "kv_cache",     # prefill's cache build; decode's write of every
+                    # layer's slot after the stack (a lane-aligned block
+                    # of slots for narrow entries), and the read's select
+                    # and cast (fused into the attention's dots on a TPU)
     "mlp",
     "moe",
     "ssm",
