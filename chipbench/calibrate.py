@@ -32,7 +32,7 @@ def eval_rows(conf, mix, seeds, devices):
         if i:
             sess.params = None
             gc.collect()
-            sess.params = common.program_weights(seed)(sess.model)
+            sess.params = common.program_weights(seed, conf)(sess.model)
         traffic = kind.EvalTraffic(seed, conf["vocab_size"], mix["batch"],
                                    mix["prompt_len"])
         served, _, _ = kind.window(sess, traffic, mix["gen_len"], 0.0,
@@ -55,7 +55,7 @@ def train_rows(conf, mix, seeds, devices):
         with tempfile.TemporaryDirectory(prefix="chipbench_ckpt_") as d:
             trainer = kind.build(conf, mix, seed, devices, d)
             hook = kind.StepHook(trainer, trainer.step_fn, mix, 0.0)
-            kind.run_job(trainer, hook, seed)
+            kind.run_job(trainer, hook, seed, conf)
             hook.finish()
             prog = kind.program_readings(hook)
             del trainer, hook

@@ -2,14 +2,16 @@
 
 Everything here is found by name from ``BENCHMARK.json``: a cell names a
 configuration (``configs/<name>.json``) and a traffic mix
-(``traffic/<name>.json``), the mix names its kind (``kinds/<kind>.py``), each
-per-layer metric has a reader (``metrics/<name>.py``) and each cell its
+(``traffic/<name>.json``), the configuration names its architecture family
+(``reference/<reference>.py``), the mix names its kind (``kinds/<kind>.py``),
+each per-layer metric has a reader (``metrics/<name>.py``) and each cell its
 limits (``limits/<cell>.json``). Adding any of these adds files; no code here
 changes.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib.util
 import json
 import os
@@ -64,6 +66,20 @@ def load_module(kind: str, name: str):
     return mod
 
 
+@functools.cache
+def family(name: str):
+    """``reference/<name>.py``, loaded once: an architecture family's plain
+    reference and what the benchmark knows of the family besides, namely
+
+    - ``program_keys(cfg)``: the configuration file's keys as the program's
+      ModelConfig states them, ``reference`` among them (the family's name
+      where the program's architecture is the family's);
+    - ``WEIGHT_SCALES``: each weight's scale by the end of its name (see
+      ``leaf_value``);
+    - the operations and bytes that the work needs (see ``flops.py``)."""
+    return load_module("reference", name)
+
+
 def use_program() -> None:
     """Put the program's sources on the import path."""
     if SRC not in sys.path:
@@ -114,30 +130,33 @@ def base_key(seed: int):
     return jax.random.fold_in(key, seed >> 32)
 
 
-# number of leading input dimensions of each matrix, by leaf name: the
-# fan-in that sets its scale
-_FAN_IN_DIMS = {"wq": 1, "wk": 1, "wv": 1, "wo": 2, "w1": 1, "w2": 1,
-                "w3": 1, "w": 1}
+# a weight table's rule for a norm's scale: values around 1, so the norms
+# count
+NORM = "norm"
 
 
-def leaf_value(key, name: str, shape: tuple[int, ...]):
-    """One weight of one layer, fp32, from its key: unit-variance outputs
-    (normal over the fan-in), norm scales around 1 so the norms count."""
+def leaf_value(key, name: str, shape: tuple[int, ...], family_name: str):
+    """One weight of one layer, fp32, from its key, by the rule that the
+    family's ``WEIGHT_SCALES`` gives the longest end of its name ('moe/w1'
+    before 'w1'): a tuple of the axes of ``shape`` that are its fan-in, for
+    unit-variance outputs (normal over the fan-in), or ``NORM``."""
     import jax
     import jax.numpy as jnp
-    base = name.rsplit("/", 1)[-1]
-    if base not in _FAN_IN_DIMS and base not in ("scale", "tok"):
+    table = family(family_name).WEIGHT_SCALES
+    parts = name.split("/")
+    ends = ("/".join(parts[i:]) for i in range(len(parts)))
+    rule = next((table[e] for e in ends if e in table), None)
+    if rule is None:
         # a bias, say: the configuration files state none, and the
         # reference has none
-        raise BenchError(f"weight {name!r} is not one the reference has")
+        raise BenchError(f"weight {name!r} is not one the {family_name} "
+                         f"reference has")
     z = jax.random.normal(key, shape, jnp.float32)
-    if base == "scale":
+    if rule == NORM:
         return 1.0 + 0.1 * z
-    if base == "tok":
-        return z * shape[-1] ** -0.5
     fan_in = 1
-    for n in shape[:_FAN_IN_DIMS[base]]:
-        fan_in *= n
+    for axis in rule:
+        fan_in *= shape[axis]
     return z * fan_in ** -0.5
 
 
@@ -159,36 +178,75 @@ def is_stacked(name: str) -> bool:
     return name.startswith("segments/")
 
 
-def make_weights(seed: int, abstract: Any, shardings: Any = None) -> Any:
+def stacked_layers(flat: list) -> list:
+    """For each leaf of a flattened tree (``tree_flatten_with_path``), the
+    global layer of each entry along its leading dimension, or None for a
+    leaf outside 'segments'.
+
+    The program keeps its layers as segments (``params['segments'][s]``),
+    each a pattern of layers (``[s][p]``) whose leaves stack its repeats:
+    entry r of place p in segment s is layer first(s) + r * period(s) + p,
+    where a segment's period is its number of places and its first layer
+    follows the layers of the segments before it. A tree with no such
+    indices is one segment of period 1."""
+    import jax
+    places, segs = [], {}
+    for path, s in flat:
+        if not is_stacked(leaf_name(path)):
+            places.append(None)
+            continue
+        seg, place = ([p.idx for p in path
+                       if isinstance(p, jax.tree_util.SequenceKey)]
+                      + [0, 0])[:2]
+        period, repeat = segs.get(seg, (0, s.shape[0]))
+        segs[seg] = (max(period, place + 1), repeat)
+        places.append((seg, place))
+    first, n = {}, 0
+    for seg in sorted(segs):
+        first[seg] = n
+        n += segs[seg][0] * segs[seg][1]
+    return [None if pl is None else
+            [first[pl[0]] + r * segs[pl[0]][0] + pl[1]
+             for r in range(segs[pl[0]][1])]
+            for pl in places]
+
+
+def make_weights(seed: int, conf: dict, abstract: Any,
+                 shardings: Any = None) -> Any:
     """The weights of a tree of ShapeDtypeStructs, made on the device in
-    one jitted call from the seed, placed by ``shardings``. Leaf values
-    depend only on (seed, leaf name, layer), so a reference that makes one
-    layer at a time gets the same numbers."""
+    one jitted call from the seed, scaled by the configuration's family,
+    placed by ``shardings``. Leaf values depend only on (seed, leaf name,
+    global layer), so a reference that makes one layer at a time gets the
+    same numbers."""
     import jax
     import jax.numpy as jnp
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
     names = [leaf_name(p) for p, _ in flat]
-    if len(set(names)) != len(names):
-        raise BenchError("weight names collide: the reference covers models "
-                         "of one repeated layer")
+    layers = stacked_layers(flat)
+    pairs = [(n, i) for n, ls in zip(names, layers) for i in (ls or [0])]
+    if len(set(pairs)) != len(pairs):
+        raise BenchError("a weight's (name, layer) repeats: the reference "
+                         "draws each layer's weight once")
+    family_name = conf["reference"]
 
     def build(seed_key):
         leaves = []
-        for name, (_, s) in zip(names, flat):
-            if is_stacked(name):
+        for name, ls, (_, s) in zip(names, layers, flat):
+            if ls is not None:
                 def one(layer, _name=name, _shape=s.shape[1:]):
                     return leaf_value(leaf_key(seed_key, _name, layer),
-                                      _name, _shape)
-                v = jax.vmap(one)(jnp.arange(s.shape[0]))
+                                      _name, _shape, family_name)
+                v = jax.vmap(one)(jnp.asarray(ls, jnp.int32))
             else:
-                v = leaf_value(leaf_key(seed_key, name, 0), name, s.shape)
+                v = leaf_value(leaf_key(seed_key, name, 0), name, s.shape,
+                               family_name)
             leaves.append(v.astype(s.dtype))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return jax.jit(build, out_shardings=shardings)(base_key(seed))
 
 
-def program_weights(seed: int) -> Callable:
+def program_weights(seed: int, conf: dict) -> Callable:
     """A stand-in for the program's ``Model.init``: the benchmark's weights
     from ``seed``, on the shardings the program's own rules choose."""
     use_program()
@@ -198,19 +256,19 @@ def program_weights(seed: int) -> Callable:
         specs = model.specs()
         shardings = (tree_shardings(model.rules, specs)
                      if model.rules is not None else None)
-        return make_weights(seed, model.abstract(), shardings)
+        return make_weights(seed, conf, model.abstract(), shardings)
 
     return init
 
 
 @contextlib.contextmanager
-def seeded_init(seed: int):
+def seeded_init(seed: int, conf: dict):
     """While the block runs, the program's ``Model.init`` makes the
     benchmark's weights from ``seed`` (its own key is ignored); after it,
     ``Model.init`` is the program's own again."""
     use_program()
     from repro.models import Model
-    own, init = Model.init, program_weights(seed)
+    own, init = Model.init, program_weights(seed, conf)
     Model.init = lambda self, key: init(self)
     try:
         yield
@@ -242,38 +300,19 @@ def program_embedding_scale(d_model: int) -> float:
 def check_program_config(cfg, conf: dict) -> None:
     """The program runs the configuration the file states, or BenchError.
 
-    Each key under the file's ``departures`` (where what it states, and the
-    program runs, differs from the source) has to be one checked here."""
-    a = cfg.attention
-    got = {
-        "num_hidden_layers": cfg.num_layers,
-        "hidden_size": cfg.d_model,
-        "intermediate_size": cfg.d_ff,
-        "num_attention_heads": a.num_heads,
-        "num_key_value_heads": a.num_kv_heads,
-        "head_dim": a.head_dim,
-        "vocab_size": cfg.vocab_size,
-        "tie_word_embeddings": cfg.tie_embeddings,
-        "rope_theta": a.rope_theta,
-        "rms_norm_eps": cfg.norm_eps,
-        "hidden_act": cfg.mlp_act.split("_")[0],
-        "compute_dtype": cfg.dtype,
-        # the program's layers have no bias; a bias leaf would be refused
-        # where the weights are made (``leaf_value``)
-        "bias": False,
-        "embed_scale_sqrt_hidden": abs(
-            program_embedding_scale(cfg.d_model) - cfg.d_model ** 0.5) < 1e-3,
-    }
-    bad = {k: (v, conf[k]) for k, v in got.items() if conf[k] != v}
+    The file's family (its ``reference``) says which keys are compared,
+    ``reference`` itself among them, so that the program's architecture is
+    the family's. Each key under the file's ``departures`` (where what it
+    states, and the program runs, differs from the source) has to be one
+    the family reads."""
+    got = family(conf["reference"]).program_keys(cfg)
+    bad = {k: (v, conf.get(k)) for k, v in got.items()
+           if k not in conf or conf[k] != v}
     unchecked = set(conf.get("departures", {})) - set(got)
     if unchecked:
         bad["departures"] = (sorted(unchecked), "keys this check reads")
     if cfg.padded_vocab != cfg.vocab_size:
         bad["padded_vocab"] = (cfg.padded_vocab, cfg.vocab_size)
-    if cfg.family != "dense" or a.kind != "gqa" or a.sliding_window or \
-            a.global_every or a.logit_softcap or not a.use_rope or \
-            cfg.mlp_act != "silu_glu":
-        bad["architecture"] = (cfg.family, "dense GQA, SwiGLU, RoPE")
     if bad:
         raise BenchError(f"{conf['arch']}: the program's configuration "
                          f"differs from the file (program, file): {bad}")

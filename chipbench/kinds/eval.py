@@ -63,7 +63,7 @@ def build(conf: dict, mix: dict, seed: int, devices: list):
     from repro.launch.serve import ServeSession
     cfg = common.program_config(conf)
     common.check_program_config(cfg, conf)
-    with common.seeded_init(seed):
+    with common.seeded_init(seed, conf):
         return ServeSession(cfg, model_axis=conf["model_axis"],
                             max_len=mix["prompt_len"] + max(mix["gen_len"], 1),
                             devices=devices)
@@ -195,7 +195,7 @@ def control_readings(ref_logits: np.ndarray, low_logits: np.ndarray,
 
 def reference_logits(seed: int, conf: dict, full: np.ndarray, prompt_len: int,
                      prec: str = "f32", device=None) -> np.ndarray:
-    ref = common.load_module("reference", conf["reference"])
+    ref = common.family(conf["reference"])
     # rows per block: their fp32 attention scores in about 1 GB
     seq = full.shape[1]
     rows = int(1e9 // (conf["num_attention_heads"] * seq * seq * 4))

@@ -143,12 +143,12 @@ def build(conf: dict, mix: dict, seed: int, devices: list, ckpt_dir: str):
     return trainer
 
 
-def run_job(trainer, hook, seed: int) -> None:
+def run_job(trainer, hook, seed: int, conf: dict) -> None:
     """The job's own loop, its weights made from ``seed``."""
     common.use_program()
     from repro.core.ft.supervisor import JobContext
     trainer.step_fn = hook
-    with common.seeded_init(seed):
+    with common.seeded_init(seed, conf):
         trainer.job(JobContext(start_step=0, attempt=0, skip_ranges=[],
                                healthy_nodes=1, resume_extra={}))
 
@@ -197,7 +197,7 @@ def reference_readings(seed: int, conf: dict, mix: dict, prec: str = "f32",
                        rows: int = 0) -> dict:
     """The reference's first steps; ``rows`` > 0 keeps only the first rows
     of each batch (a planted fault: part of the batch left out)."""
-    ref = common.load_module("reference", conf["reference"])
+    ref = common.family(conf["reference"])
     traffic = TrainTraffic(seed, conf["vocab_size"], mix["batch"],
                            mix["seq_len"])
     keep = slice(0, rows or mix["batch"])
@@ -214,7 +214,7 @@ def run(ctx) -> dict:
         ctx.mark("built")
         hook = StepHook(trainer, trainer.step_fn, mix, ctx.seconds,
                             tracer=ctx.tracer)
-        run_job(trainer, hook, seed)
+        run_job(trainer, hook, seed, conf)
         wall = hook.finish()
         ctx.window_started(hook.t_start)
         steps = hook.calls - hook.warmup

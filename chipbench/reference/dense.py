@@ -1,4 +1,10 @@
-"""Plain reference of a dense decoder: RMSNorm, GQA with RoPE, SwiGLU.
+"""The dense decoder family: RMSNorm, GQA with RoPE, SwiGLU.
+
+Its plain reference, and what the benchmark knows of the family besides
+(``common.family``): the keys of its configuration files as the program
+states them (``program_keys``), its weights' scales (``WEIGHT_SCALES``) and
+the operations and bytes its work needs (the counts that ``flops.py``
+dispatches to).
 
 Straight ``jax.numpy`` in float32 at the highest matmul precision, written
 from the published Llama-style description and the configuration file
@@ -23,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 import common
+import flops
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -56,6 +63,52 @@ def mm(spec: str, a, b, prec: str):
                       preferred_element_type=jnp.float32)
 
 
+# ---------------------------------------------------------------------------
+# the program's configuration against the file
+# ---------------------------------------------------------------------------
+
+def program_keys(cfg) -> dict:
+    """The file's keys as the program's ModelConfig states them; its
+    ``reference`` is this family's only where the program's architecture is
+    a dense GQA decoder with SwiGLU and RoPE."""
+    a = cfg.attention
+    if (cfg.family != "dense" or a is None or a.kind != "gqa"
+            or a.sliding_window or a.global_every or a.logit_softcap
+            or not a.use_rope or cfg.mlp_act != "silu_glu"):
+        return {"reference": f"{cfg.family}, not dense GQA, SwiGLU, RoPE"}
+    return {
+        "reference": "dense",
+        "num_hidden_layers": cfg.num_layers,
+        "hidden_size": cfg.d_model,
+        "intermediate_size": cfg.d_ff,
+        "num_attention_heads": a.num_heads,
+        "num_key_value_heads": a.num_kv_heads,
+        "head_dim": a.head_dim,
+        "vocab_size": cfg.vocab_size,
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "rope_theta": a.rope_theta,
+        "rms_norm_eps": cfg.norm_eps,
+        "hidden_act": cfg.mlp_act.split("_")[0],
+        "compute_dtype": cfg.dtype,
+        # the program's layers have no bias; a bias leaf would be refused
+        # where the weights are made (``common.leaf_value``)
+        "bias": False,
+        "embed_scale_sqrt_hidden": abs(
+            common.program_embedding_scale(cfg.d_model)
+            - cfg.d_model ** 0.5) < 1e-3,
+    }
+
+
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
+
+# each weight's fan-in axes within one layer's leaf, by the end of its name
+WEIGHT_SCALES = {"wq": (0,), "wk": (0,), "wv": (0,), "wo": (0, 1),
+                 "w1": (0,), "w2": (0,), "w3": (0,), "w": (0,),
+                 "tok": (1,), "scale": common.NORM}
+
+
 def layer_shapes(conf: dict) -> dict:
     d, ff = conf["hidden_size"], conf["intermediate_size"]
     h, kv, hd = (conf["num_attention_heads"], conf["num_key_value_heads"],
@@ -75,21 +128,23 @@ def outer_shapes(conf: dict) -> dict:
     return out
 
 
-@partial(jax.jit, static_argnums=(1,))
-def _layer_weights(seed_key, shapes_items, layer):
+@partial(jax.jit, static_argnums=(1, 2))
+def _layer_weights(seed_key, family_name, shapes_items, layer):
     return {name: common.leaf_value(common.leaf_key(seed_key, name, layer),
-                                    name, shape)
+                                    name, shape, family_name)
             for name, shape in shapes_items}
 
 
 def layer_weights(seed: int, conf: dict, layer: int) -> dict:
     items = tuple(sorted(layer_shapes(conf).items()))
-    return _layer_weights(common.base_key(seed), items, jnp.int32(layer))
+    return _layer_weights(common.base_key(seed), conf["reference"], items,
+                          jnp.int32(layer))
 
 
 def outer_weights(seed: int, conf: dict) -> dict:
     items = tuple(sorted(outer_shapes(conf).items()))
-    return _layer_weights(common.base_key(seed), items, jnp.int32(0))
+    return _layer_weights(common.base_key(seed), conf["reference"], items,
+                          jnp.int32(0))
 
 
 def all_weights(seed: int, conf: dict) -> dict:
@@ -106,7 +161,7 @@ def all_weights(seed: int, conf: dict) -> dict:
         for p in parents:
             node = node.setdefault(p, {})
         node[leaf] = s
-    return flatten(common.make_weights(seed, nested))
+    return flatten(common.make_weights(seed, conf, nested))
 
 
 def flatten(nested: dict) -> dict:
@@ -283,3 +338,80 @@ def train_steps(seed: int, conf: dict, batches: list, opt: dict,
               for k in params}
     return {"losses": losses, "grad_norms": first_norms, "grad": first_grad,
             "change_norms": change}
+
+
+# ---------------------------------------------------------------------------
+# counts: operations and bytes the work needs (``flops.py``)
+# ---------------------------------------------------------------------------
+
+def _dims(conf: dict) -> tuple[int, int, int, int, int, int, int]:
+    return (conf["num_hidden_layers"], conf["hidden_size"],
+            conf["intermediate_size"], conf["num_attention_heads"],
+            conf["num_key_value_heads"], conf["head_dim"], conf["vocab_size"])
+
+
+def layer_matmul_params(conf: dict) -> int:
+    """Weights one decoder layer multiplies by: q, k, v, o, the gated MLP."""
+    _, d, ff, h, kv, hd, _ = _dims(conf)
+    return d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * ff
+
+
+def matmul_params(conf: dict) -> int:
+    """Weights every token is multiplied by, the output head included."""
+    n_layers, d, *_, vocab = _dims(conf)
+    return n_layers * layer_matmul_params(conf) + d * vocab
+
+
+def param_count(conf: dict) -> int:
+    """All parameters: embedding, layers, norms and an untied head."""
+    n_layers, d, *_, vocab = _dims(conf)
+    norms = n_layers * 2 * d + d
+    head = 0 if conf["tie_word_embeddings"] else d * vocab
+    return vocab * d + n_layers * layer_matmul_params(conf) + norms + head
+
+
+def forward_attention_flops(conf: dict, batch: int, seq: int) -> int:
+    """QK^T and PV over the causal pairs, every layer."""
+    n_layers, _, _, h, _, hd, _ = _dims(conf)
+    return n_layers * batch * flops.attention_pairs(seq) * h * hd * 2 * 2
+
+
+def attention_sublayer_flops(conf: dict, batch: int, seq: int) -> int:
+    """The attention sublayer's forward over ``batch`` x ``seq``: the q, k, v
+    and o projections on every token and QK^T and PV over the causal pairs,
+    every layer."""
+    n_layers, d, _, h, kv, hd, _ = _dims(conf)
+    proj = 2 * d * h * hd + 2 * d * kv * hd
+    return (2 * n_layers * proj * batch * seq
+            + forward_attention_flops(conf, batch, seq))
+
+
+def train_step_flops(conf: dict, batch: int, seq: int) -> int:
+    """Forward and backward (3x the forward) of one step on batch x seq."""
+    tokens = batch * seq
+    return (6 * matmul_params(conf) * tokens
+            + 3 * forward_attention_flops(conf, batch, seq))
+
+
+def prefill_flops(conf: dict, batch: int, seq: int) -> int:
+    """Forward pass over the prompt; the head on the last position only."""
+    n_layers, d, *_, vocab = _dims(conf)
+    return (2 * n_layers * layer_matmul_params(conf) * batch * seq
+            + forward_attention_flops(conf, batch, seq)
+            + 2 * batch * d * vocab)
+
+
+def decode_step_bytes(conf: dict, batch: int, position: int) -> int:
+    """HBM bytes one decode step must read and write at ``position``.
+
+    Every weight once at the compute dtype (bf16), except that an untied
+    embedding table is read only at the batch's rows; the K and V entries of
+    the ``position + 1`` valid positions, read; the new entry, written.
+    """
+    n_layers, d, _, _, kv, hd, vocab = _dims(conf)
+    weights = param_count(conf)
+    if not conf["tie_word_embeddings"]:
+        weights -= vocab * d - batch * d
+    kv_entry = n_layers * 2 * kv * hd * flops.BF16_BYTES
+    return (weights * flops.BF16_BYTES + batch * (position + 1) * kv_entry
+            + batch * kv_entry)

@@ -111,8 +111,9 @@ def per_layer(layer: list, trace: dict, tracer: Tracer, conf: dict,
               peaks: dict, chips: int) -> dict:
     """Each per-layer metric's reader; one that finds nothing is left out."""
     import types
-    view = types.SimpleNamespace(trace=trace, counters=tracer.counters,
-                                 conf=conf, peaks=peaks, chips=chips)
+    view = types.SimpleNamespace(trace=trace, trace_dir=tracer.dir,
+                                 counters=tracer.counters, conf=conf,
+                                 peaks=peaks, chips=chips)
     out = {}
     for m in layer:
         value = common.load_module("metrics", m["name"]).read(view)

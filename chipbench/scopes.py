@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import bisect
 import re
-import sys
 from collections import defaultdict
 
 import common
@@ -270,30 +269,14 @@ def reduce_dir(trace_dir: str, n_devices: int) -> dict:
 
 def of_view(view) -> dict | None:
     """``reduce_dir`` of the traced run whose per-layer metrics ``view``
-    serves, made once per view; None where the run left no trace.
-
-    The harness gives its readers the reduction of ``trace.py`` and not the
-    trace's directory, so the directory is ``view.trace_dir`` where that is
-    set, else that of the ``tracer`` that a caller of the reader holds
-    (``run.per_layer``)."""
+    serves (its ``trace_dir``), made once per view; None where the run left
+    no trace."""
     if not hasattr(view, "scopes_reduction"):
-        trace_dir = getattr(view, "trace_dir", None) or _tracer_dir()
         try:
-            view.scopes_reduction = (reduce_dir(trace_dir, view.chips)
-                                     if trace_dir else None)
+            view.scopes_reduction = reduce_dir(view.trace_dir, view.chips)
         except FileNotFoundError:
             view.scopes_reduction = None
     return view.scopes_reduction
-
-
-def _tracer_dir() -> str | None:
-    frame = sys._getframe(1)
-    while frame is not None:
-        tracer = frame.f_locals.get("tracer")
-        if isinstance(getattr(tracer, "dir", None), str):
-            return tracer.dir
-        frame = frame.f_back
-    return None
 
 
 def scope_share(view, program: str, scope: str, span: str) -> float | None:

@@ -57,3 +57,17 @@ def test_decode_bytes_grow_with_position_only_by_kv():
     # tied: the whole table is read as the head
     assert a - 64 * 1025 * 32 * 2 * 5 * 64 * 2 - 64 * 32 * 2 * 5 * 64 * 2 \
         == 2 * flops.param_count(SMOLLM)
+
+
+def test_smollm_attention_sublayer_by_hand():
+    # 64 x 1024 tokens, 32 layers; 2 flops a multiply-add. Projections:
+    # q and o 960 <-> 15 x 64, k and v 960 -> 5 x 64, on every token
+    tokens = 64 * 1024
+    q = o = 2 * 960 * 960 * tokens
+    k = v = 2 * 960 * 320 * tokens
+    # QK^T and PV: 15 heads of 64 over each row's 1024 * 1025 / 2 causal
+    # query-key pairs
+    qk = pv = 2 * 64 * (1024 * 1025 // 2) * 15 * 64
+    assert flops.attention_sublayer_flops(SMOLLM, 64, 1024) == 32 * (
+        q + k + v + o + qk + pv)
+    assert 1.4e13 < flops.attention_sublayer_flops(SMOLLM, 64, 1024) < 1.5e13

@@ -133,7 +133,8 @@ def test_check_program_config_refuses_an_unstated_departure(change):
 
 def test_a_weight_the_reference_lacks_is_refused():
     with pytest.raises(common.BenchError, match="bias"):
-        common.leaf_value(common.base_key(1), "segments/attn/bias", (4,))
+        common.leaf_value(common.base_key(1), "segments/attn/bias", (4,),
+                          "dense")
 
 
 def test_check_program_config_refuses_a_changed_width():

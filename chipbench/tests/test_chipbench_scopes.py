@@ -208,8 +208,8 @@ def test_program_span_arguments_are_what_the_recorder_served(program):
 
 def test_new_metrics_read_the_program_trace_through_the_harness(
         program_dir):
-    """run.per_layer gives readers no trace directory: they find it on the
-    harness's tracer."""
+    """run.per_layer gives readers the trace directory of the harness's
+    tracer."""
     run = common.load_module("", "run")
     layer = [m for m in common.manifest()["per_layer"]
              if m["name"] in NEW_METRICS]
@@ -223,16 +223,15 @@ def test_new_metrics_read_the_program_trace_through_the_harness(
 
 
 def test_attention_sublayer_flops_by_hand():
-    attn = common.load_module("metrics", "attn_mfu.prefill")
     flops = common.load_module("", "flops")
     conf = common.load_json(os.path.join(common.HERE, "configs",
                                          "smollm-360m.json"))
     # q and o 960 x 15 x 64, k and v 960 x 5 x 64; 32 layers
     proj = 2 * 960 * 960 + 2 * 960 * 320
     core = 32 * 32 * (512 * 513 // 2) * 15 * 64 * 4
-    assert attn.attention_sublayer_flops(conf, 32, 512) == (
+    assert flops.attention_sublayer_flops(conf, 32, 512) == (
         2 * 32 * proj * 32 * 512 + core)
-    assert (attn.attention_sublayer_flops(conf, 32, 512)
+    assert (flops.attention_sublayer_flops(conf, 32, 512)
             < flops.prefill_flops(conf, 32, 512))
 
 
@@ -278,7 +277,7 @@ def test_train_step_spans_equal_the_harness_counters(monkeypatch,
     trainer = kind.build(conf, mix, 5, jax.devices()[:1], str(tmp_path))
     hook = kind.StepHook(trainer, trainer.step_fn, mix, 1.0,
                          tracer=short_trace)
-    kind.run_job(trainer, hook, 5)
+    kind.run_job(trainer, hook, 5, conf)
     hook.finish()
     spans = scopes.reduce_dir(short_trace.dir, 1)["program_spans"]
     counters = short_trace.counters["train"]
@@ -288,3 +287,48 @@ def test_train_step_spans_equal_the_harness_counters(monkeypatch,
         tuple(s) for s in counters[:-1]]
     assert [c["step_num"] for c in calls] == list(
         range(mix["warmup_calls"], mix["warmup_calls"] + len(calls)))
+
+
+# every reader's value on the recorded traces, through the harness, as the
+# readers read them before the families' counts moved out of flops.py
+PINNED = {
+    rec.FILE: {
+        "idle_share.train": 99.87071748713319,
+        "train_mfu": 0.7129310377130204,
+        "idle_share.eval": 99.87071748713319,
+        "prefill_mfu": 2.580912522645165,
+        "decode_mbu": 1.8563355134317487,
+        "collective_share.decode": 0.0,
+        "kv_cache_share.decode": 6.816504137689888,
+        "layer_scan_share.decode": 2.898325498271105,
+        "idle_share.decode": 99.43220348561223,
+        "attn_mfu.prefill": 1.4763711267783008,
+        "layer_scan_share.train": 2.898325498271105},
+    "eval_1chip.xplane.pb.gz": {
+        "idle_share.train": 99.24713011695626,
+        "idle_share.eval": 99.24713011695626,
+        "prefill_mfu": 2.619641800660315,
+        "decode_mbu": 5.197598083296053,
+        "collective_share.decode": 0.0},
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_every_reader_reads_the_recorded_traces_as_pinned(
+        tmp_path_factory, name):
+    """The recorders' work as the harness counts it: a prefill of 4 x 32
+    and two decode steps, and in the program trace a train step of 2 x
+    32."""
+    trace_dir = unpack(tmp_path_factory, name)
+    counters = {"prefill": [(rec.SERVE_BATCH, rec.PROMPT_LEN)],
+                "decode": [(rec.SERVE_BATCH, rec.PROMPT_LEN + j)
+                           for j in range(rec.DECODE_STEPS)],
+                "train": ([(rec.TRAIN_BATCH, rec.SEQ_LEN)]
+                          if name == rec.FILE else [])}
+    run = common.load_module("", "run")
+    tracer = types.SimpleNamespace(dir=trace_dir, counters=counters)
+    out = run.per_layer(common.manifest()["per_layer"],
+                        trace.reduce_dir(trace_dir, 1), tracer,
+                        tl.smoke_conf("smollm-360m"),
+                        common.peaks("TPU v5 lite"), 1)
+    assert {k: m["value"] for k, m in out.items()} == PINNED[name]

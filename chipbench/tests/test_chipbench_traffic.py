@@ -43,9 +43,10 @@ def test_weights_follow_the_seed_layer_by_layer():
     tree = {"embed": {"tok": jax.ShapeDtypeStruct((64, 8), jnp.float32)},
             "segments": [({"mlp": {"w1": jax.ShapeDtypeStruct(
                 (3, 8, 16), jnp.float32)}},)]}
-    w = common.make_weights(BIG, tree)
-    again = common.make_weights(BIG, tree)
-    other = common.make_weights(BIG + 2 ** 32, tree)
+    conf = {"reference": "dense"}
+    w = common.make_weights(BIG, conf, tree)
+    again = common.make_weights(BIG, conf, tree)
+    other = common.make_weights(BIG + 2 ** 32, conf, tree)
     w1 = np.asarray(w["segments"][0][0]["mlp"]["w1"])
     assert np.array_equal(w1, np.asarray(again["segments"][0][0]["mlp"]["w1"]))
     assert not np.array_equal(
@@ -53,6 +54,6 @@ def test_weights_follow_the_seed_layer_by_layer():
     # one layer alone, jitted as the reference makes it: the same numbers
     layer1 = jax.jit(lambda k, layer: common.leaf_value(
         common.leaf_key(k, "segments/mlp/w1", layer), "segments/mlp/w1",
-        (8, 16)))(common.base_key(BIG), jnp.int32(1))
+        (8, 16), "dense"))(common.base_key(BIG), jnp.int32(1))
     np.testing.assert_array_equal(np.asarray(layer1), w1[1])
     assert abs(float(w1.std()) - 8 ** -0.5) < 0.1
